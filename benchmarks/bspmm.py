@@ -28,7 +28,6 @@ from benchmarks.common import CSV, block, mesh_1d, time_fn
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
 from repro.launch.roofline import collective_critical_depth
-from repro.compat import shard_map
 
 N_WORKERS = 8
 
@@ -73,10 +72,10 @@ def build(mode: str, tile: int, mesh):
             outs.append(rt.accumulate(c, accw[w], axis="data"))
         return rt.barrier(jnp.stack(outs))
 
-    f = jax.jit(shard_map(step, mesh=mesh,
-                          in_specs=(P(None, None, None),) * 2,
-                          out_specs=P(None, None, None),
-                          check_vma=False))
+    f = jax.jit(jax.shard_map(step, mesh=mesh,
+                              in_specs=(P(None, None, None),) * 2,
+                              out_specs=P(None, None, None),
+                              check_vma=False))
     a = jnp.ones((N_WORKERS, tile, tile), jnp.float32)
     return f, a
 

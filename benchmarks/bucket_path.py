@@ -61,7 +61,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import CSV, SMOKE, block, emit_json, mesh_1d, time_fn
-from repro.compat import shard_map
 from repro.core import TILE, get_comm_plan, plan_cache_clear, \
     plan_cache_stats, reduce_gradients
 from repro.core.bucketing import ShardLayout, all_gather_shards, plan_buckets
@@ -111,8 +110,8 @@ def make_step(mesh, tree, *, pack: str, reduction: str, persistent: bool,
                                pack=pack, reduction=reduction)
         return rt.barrier(red)
 
-    f = shard_map(run, mesh=mesh, in_specs=(spec_in,),
-                  out_specs=spec_in, check_vma=False)
+    f = jax.shard_map(run, mesh=mesh, in_specs=(spec_in,),
+                      out_specs=spec_in, check_vma=False)
     return f, (tree,)
 
 
@@ -145,10 +144,10 @@ def make_step_zero1(mesh, tree, *, pack: str, persistent: bool, streams: int,
                                    wire_dtype=wire)
         return rt.barrier((params, new_st))
 
-    f = shard_map(run, mesh=mesh,
-                  in_specs=(spec_in, spec_state,
-                            tuple(P("data") for _ in masks)),
-                  out_specs=(spec_in, spec_state), check_vma=False)
+    f = jax.shard_map(run, mesh=mesh,
+                      in_specs=(spec_in, spec_state,
+                                tuple(P("data") for _ in masks)),
+                      out_specs=(spec_in, spec_state), check_vma=False)
     return f, (tree, state, masks)
 
 

@@ -26,7 +26,6 @@ from benchmarks.common import CSV, block, mesh_1d, time_fn
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
 from repro.launch.roofline import collective_critical_depth
-from repro.compat import shard_map
 
 N_WORKERS = 8
 
@@ -64,8 +63,8 @@ def build(mode: str, band_elems: int, mesh):
         flushed = [rt.flush(f_, wins[w]) for w, f_ in enumerate(fetched)]
         return rt.barrier(jnp.stack(flushed))
 
-    f = jax.jit(shard_map(step, mesh=mesh, in_specs=P(None, None),
-                          out_specs=P(None, None), check_vma=False))
+    f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(None, None),
+                              out_specs=P(None, None), check_vma=False))
     x = jnp.ones((N_WORKERS, band_elems), jnp.float32)
     return f, x
 
@@ -104,9 +103,9 @@ def build_busy_target(mode: str, burn_iters: int, mesh, band_elems=16384):
         flushed = [rt.flush(f_, wins[k]) for k, f_ in enumerate(fetched)]
         return rt.barrier(jnp.stack(flushed))
 
-    f = jax.jit(shard_map(step, mesh=mesh,
-                          in_specs=(P(None, None), P()),
-                          out_specs=P(None, None), check_vma=False))
+    f = jax.jit(jax.shard_map(step, mesh=mesh,
+                              in_specs=(P(None, None), P()),
+                              out_specs=P(None, None), check_vma=False))
     x = jnp.ones((N_WORKERS, band_elems), jnp.float32)
     w = jnp.eye(16, dtype=jnp.float32) * 0.5
     return f, x, w

@@ -19,7 +19,6 @@ from benchmarks.common import CSV, block, mesh_1d, time_fn
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
 from repro.launch.roofline import collective_critical_depth
-from repro.compat import shard_map
 
 N_STREAMS = 16
 OPS = 8
@@ -48,8 +47,8 @@ def build(pool_size: int, mesh, *, policy="fcfs", pin=False):
             outs.append(v)
         return rt.barrier(jnp.stack(outs))
 
-    f = jax.jit(shard_map(step, mesh=mesh, in_specs=P(None, None),
-                          out_specs=P(None, None), check_vma=False))
+    f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(None, None),
+                              out_specs=P(None, None), check_vma=False))
     return f
 
 

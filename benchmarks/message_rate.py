@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 from benchmarks.common import CSV, SMOKE, block, mesh_1d, time_fn
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
-from repro.compat import shard_map
 
 OPS_PER_STREAM = 16
 
@@ -115,8 +114,8 @@ def build_step(mode: str, n_streams: int, msg_elems: int, *, rma: bool,
             outs.append(v)
         return rt.barrier(jnp.stack(outs))
 
-    f = jax.jit(shard_map(step, mesh=mesh, in_specs=P(None, None),
-                          out_specs=P(None, None), check_vma=False))
+    f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(None, None),
+                              out_specs=P(None, None), check_vma=False))
     x = jnp.ones((n_streams, msg_elems), jnp.float32)
     hlo = f.lower(x).compile().as_text()
     f(x)  # warm

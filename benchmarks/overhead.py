@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 from benchmarks.common import CSV, block, mesh_1d, time_fn
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
-from repro.compat import shard_map
 
 OPS = 32
 
@@ -46,8 +45,8 @@ def build(mode: str, n_streams: int, mesh, msg=128):
             outs.append(v)
         return rt.barrier(jnp.stack(outs))
 
-    return jax.jit(shard_map(step, mesh=mesh, in_specs=P(None, None),
-                             out_specs=P(None, None), check_vma=False))
+    return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(None, None),
+                                 out_specs=P(None, None), check_vma=False))
 
 
 def main():

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import CSV, SMOKE, block, emit_json, mesh_1d, time_fn
-from repro.compat import set_mesh
 from repro.core import get_comm_plan
 from repro.launch.roofline import collective_critical_depth
 
@@ -221,7 +220,7 @@ def measure_cell(mesh, cfg, batch, *, schedule: str, optimizer: str,
     step = make_train_step(cfg, mesh=mesh, comm="vci", num_streams=streams,
                            num_vcis=num_vcis, token_impl="data",
                            optimizer=optimizer, schedule=schedule)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step)
         hlo = jitted.lower(state, batch).compile().as_text()
         jitted(state, batch)

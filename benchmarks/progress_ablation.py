@@ -29,7 +29,6 @@ from repro.core.bucketing import TILE, plan_buckets, reduce_gradients
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
 from repro.launch.roofline import collective_critical_depth
-from repro.compat import shard_map
 
 N_STREAMS = 8
 
@@ -60,8 +59,8 @@ def build(variant: str, mesh):
 
     in_specs = jax.tree_util.tree_map(lambda _: P("data"), tree)
     out_specs = jax.tree_util.tree_map(lambda _: P(), tree)
-    f = jax.jit(shard_map(step, mesh=mesh, in_specs=(in_specs,),
-                          out_specs=out_specs, check_vma=False))
+    f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(in_specs,),
+                              out_specs=out_specs, check_vma=False))
     return f, tree
 
 
@@ -133,8 +132,8 @@ def bench_receiver(mesh):
                     out = sum(sent)
                 return rt.barrier(out)
 
-            f = jax.jit(shard_map(step, mesh=mesh, in_specs=P(None, None),
-                                  out_specs=P(None), check_vma=False))
+            f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(None, None),
+                                      out_specs=P(None), check_vma=False))
             x = jnp.ones((n_senders, 256), jnp.float32)
             hlo = f.lower(x).compile().as_text()
             f(x)

@@ -32,7 +32,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from benchmarks.common import CSV, SMOKE, block, emit_json, time_fn
-from repro.compat import set_mesh, shard_map
 from repro.configs import get_config
 from repro.launch.roofline import collective_critical_depth
 from repro.models.transformer import Model, init_cache, init_params
@@ -86,7 +85,7 @@ def make_multilane_step(cfg, mesh, plan: ServeCommPlan, lanes: int):
             return tuple(out_t), tuple(out_c)
 
         cspecs = tuple(serve_cache_specs(c, tp, nshard) for c in caches)
-        f = shard_map(
+        f = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(serve_param_specs(cfg, params, tp),
                       tuple(P(bd, None) for _ in toks), cspecs),
@@ -104,7 +103,7 @@ def run_cell(cfg, params, mesh, *, batch: int, lanes: int, num_vcis: int,
     rng = np.random.default_rng(0)
     prefill = jax.jit(make_prefill(cfg, mesh, plan))
     toks, caches = [], []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for g in range(lanes):
             prompts = rng.integers(0, cfg.vocab_size, (batch, PROMPT),
                                    dtype=np.int32)

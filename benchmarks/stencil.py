@@ -30,7 +30,6 @@ from benchmarks.common import CSV, block, time_fn
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
 from repro.launch.roofline import collective_critical_depth
-from repro.compat import shard_map
 
 
 def grid_mesh(rows, cols):
@@ -100,9 +99,9 @@ def build(mode: str, rows, cols, block_size: int, mesh):
         out = 0.25 * (up + dn + lf + rg)
         return rt.barrier(out) if rt is not None else out
 
-    f = jax.jit(shard_map(halo_exchange, mesh=mesh,
-                          in_specs=P("y", "x"), out_specs=P("y", "x"),
-                          check_vma=False))
+    f = jax.jit(jax.shard_map(halo_exchange, mesh=mesh,
+                              in_specs=P("y", "x"), out_specs=P("y", "x"),
+                              check_vma=False))
     u = jnp.ones((rows * block_size, cols * block_size), jnp.float32)
     return f, u
 

@@ -21,7 +21,6 @@ import argparse
 import jax
 
 from benchmarks.common import CSV, SMOKE, block, mesh_1d, time_fn
-from repro.compat import set_mesh
 from repro.configs import get_config
 from repro.data.pipeline import synthetic_batch
 from repro.launch.roofline import collective_critical_depth
@@ -64,7 +63,7 @@ def main():
                                    persistent_plan=not args.per_step_plan,
                                    optimizer=args.optimizer,
                                    zero1_wire_dtype=args.zero1_wire)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 jitted = jax.jit(step)
                 compiled = jitted.lower(state, batch).compile()
                 hlo = compiled.as_text()

@@ -19,7 +19,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
 from repro.launch.roofline import collective_critical_depth
-from repro.compat import shard_map
 
 TILE = 64
 WORKERS = 4
@@ -49,7 +48,7 @@ def main():
                 b = rt.get(b_tiles[w], getw[w], axis="data", perm=perm)
                 c = c + rt.accumulate(a @ b, cwin, axis="data")
             return rt.barrier(c)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             step, mesh=mesh, in_specs=(P(None, None, None),) * 2,
             out_specs=P(None, None), check_vma=False))
 

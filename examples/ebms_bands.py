@@ -18,7 +18,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
-from repro.compat import shard_map
 
 WORKERS = 4
 BAND = 4096
@@ -47,9 +46,9 @@ def main():
             flushed = [rt.flush(f, wins[w]) for w, f in enumerate(fetched)]
             tracked = [jnp.tanh(f).sum() for f in flushed]
             return rt.barrier((jnp.stack(flushed), jnp.stack(tracked)))
-        return jax.jit(shard_map(step, mesh=mesh, in_specs=P(None, None),
-                                 out_specs=(P(None, None), P(None)),
-                                 check_vma=False))
+        return jax.jit(jax.shard_map(step, mesh=mesh, in_specs=P(None, None),
+                                     out_specs=(P(None, None), P(None)),
+                                     check_vma=False))
 
     rng = np.random.default_rng(0)
     bands = jnp.asarray(rng.normal(size=(WORKERS, BAND)), jnp.float32)

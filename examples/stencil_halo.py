@@ -16,7 +16,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.collectives import CommRuntime
 from repro.core.comm import CommWorld
-from repro.compat import shard_map
 
 ROWS = COLS = 2
 BLOCK = 32
@@ -78,8 +77,8 @@ def main():
             u = jacobi_step(u, rt, ctxs, pm)
         return rt.barrier(u)
 
-    f = jax.jit(shard_map(run, mesh=mesh, in_specs=P("y", "x"),
-                          out_specs=P("y", "x"), check_vma=False))
+    f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P("y", "x"),
+                              out_specs=P("y", "x"), check_vma=False))
 
     rng = np.random.default_rng(0)
     u0 = jnp.asarray(rng.normal(size=(ROWS * BLOCK, COLS * BLOCK)),

@@ -659,13 +659,7 @@ def reduce_gradients(
 
 
 def _axis_size(axis) -> int:
-    from repro.compat import axis_size
-    if isinstance(axis, (tuple, list)):
-        n = 1
-        for a in axis:
-            n *= axis_size(a)
-        return n
-    return axis_size(axis)
+    return jax.lax.axis_size(tuple(axis) if isinstance(axis, list) else axis)
 
 
 # ---------------------------------------------------------------------------

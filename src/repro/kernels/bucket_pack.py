@@ -73,11 +73,23 @@ def build_tile_tables(src_off, dst_off, sizes, padded_size: int,
     return block, valid
 
 
+# Scalar-prefetched tables live in SMEM (1 MiB on v5e), two int32 entries
+# per tile: one call covers at most this many tiles, and a longer buffer is
+# written chunk by chunk into one aliased output.
+MAX_TILES_PER_CALL = 1 << 16
+
+
 def _kernel(block_ref, valid_ref, src_ref, out_ref, *, tile: int):
     t = pl.program_id(0)
     v = valid_ref[t]
     idx = jax.lax.broadcasted_iota(jnp.int32, (tile,), 0)
     out_ref[...] = jnp.where(idx < v, src_ref[...], 0.0).astype(out_ref.dtype)
+
+
+def _chunk_kernel(block_ref, valid_ref, src_ref, prev_ref, out_ref, *,
+                  tile: int):
+    del prev_ref  # aliased to out_ref: tiles outside this chunk keep it
+    _kernel(block_ref, valid_ref, src_ref, out_ref, tile=tile)
 
 
 def bucket_pack_pallas(src: jax.Array, block: jax.Array, valid: jax.Array,
@@ -90,21 +102,31 @@ def bucket_pack_pallas(src: jax.Array, block: jax.Array, valid: jax.Array,
     assert padded_size % tile == 0
     assert src.shape[0] % tile == 0
     n_tiles = padded_size // tile
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile,),
-                         lambda t, block_ref, valid_ref: (block_ref[t],)),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda t, b, v: (t,)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, tile=tile),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((padded_size,), src.dtype),
-        interpret=interpret,
-    )(block, valid, src)
+    block = jnp.asarray(block, jnp.int32)
+    valid = jnp.asarray(valid, jnp.int32)
+    out_shape = jax.ShapeDtypeStruct((padded_size,), src.dtype)
+    in_tile = pl.BlockSpec((tile,), lambda t, b, v: (b[t],))
+    out = None
+    for t0 in range(0, n_tiles, MAX_TILES_PER_CALL):
+        n = min(MAX_TILES_PER_CALL, n_tiles - t0)
+        out_tile = pl.BlockSpec((tile,), lambda t, b, v, t0=t0: (t + t0,))
+        tables = (block[t0:t0 + n], valid[t0:t0 + n])
+        if out is None:
+            kernel, in_specs, args, aliases = _kernel, [in_tile], (src,), {}
+        else:
+            kernel = _chunk_kernel
+            in_specs = [in_tile, pl.BlockSpec(memory_space=pl.ANY)]
+            args, aliases = (src, out), {3: 0}
+        out = pl.pallas_call(
+            functools.partial(kernel, tile=tile),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(n,), in_specs=in_specs,
+                out_specs=out_tile),
+            out_shape=out_shape,
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(*tables, *args)
+    return out
 
 
 def bucket_unpack_pallas(packed: jax.Array, block: jax.Array,

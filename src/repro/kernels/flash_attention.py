@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -132,7 +131,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

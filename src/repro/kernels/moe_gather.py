@@ -31,6 +31,11 @@ def row_gather_pallas(src, idx, *, block_d: int = 512,
     """out[i, :] = src[idx[i], :] (0 where idx[i] < 0).
 
     src: (T, d); idx: (M,) int32 -> out: (M, d)
+
+    Rows travel as ``(T, 1, d)`` with ``(1, 1, block_d)`` blocks: a block's
+    last two dims must be multiples of the TPU tile ``(8, 128)`` or span
+    the array, and the unit middle axis spans its array where a ``(1,
+    block_d)`` block on ``(T, d)`` would not.
     """
     t, d = src.shape
     m = idx.shape[0]
@@ -41,17 +46,20 @@ def row_gather_pallas(src, idx, *, block_d: int = 512,
         num_scalar_prefetch=1,
         grid=(m, nd),
         in_specs=[
-            pl.BlockSpec((1, block_d),
-                         lambda i, j, idx_ref: (jnp.maximum(idx_ref[i], 0), j)),
+            pl.BlockSpec((1, 1, block_d),
+                         lambda i, j, idx_ref: (jnp.maximum(idx_ref[i], 0),
+                                                0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, idx_ref: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, block_d),
+                               lambda i, j, idx_ref: (i, 0, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, d), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, d), src.dtype),
         interpret=interpret,
-    )(idx, src)
+    )(idx, src.reshape(t, 1, d))
+    return out.reshape(m, d)
 
 
 def row_gather_ref(src, idx) -> jax.Array:

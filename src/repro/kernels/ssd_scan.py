@@ -20,8 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref, *, chunk: int):
@@ -85,7 +84,7 @@ def ssd_chunk_pallas(x, dt, cum, B, C, *, interpret: bool = False):
             jax.ShapeDtypeStruct((bh, nc, c, p), jnp.float32),
             jax.ShapeDtypeStruct((bh, nc, n, p), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -36,7 +36,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import build_roofline
 from repro.train.trainer import make_train_step
 from repro.serve.engine import make_serve_step
-from repro.compat import set_mesh
 
 
 def _memory_dict(compiled) -> Optional[dict]:
@@ -102,7 +101,7 @@ def lower_pair(arch: str, shape_name: str, *, multi_pod: bool,
             out_shardings=(state_sh, None),
             donate_argnums=(0,),
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(state_struct, batch_struct)
             compiled = lowered.compile()
     elif shape.kind == "prefill":
@@ -120,7 +119,7 @@ def lower_pair(arch: str, shape_name: str, *, multi_pod: bool,
             out_shardings=(I.decode_token_sharding(cfg, shape, mesh), cache_sh),
             donate_argnums=(2,),
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_struct, batch_struct, cache_struct)
             compiled = lowered.compile()
     else:  # decode
@@ -137,7 +136,7 @@ def lower_pair(arch: str, shape_name: str, *, multi_pod: bool,
             out_shardings=(tok_sh, cache_sh),
             donate_argnums=(2,),
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_struct, tok_struct, cache_struct)
             compiled = lowered.compile()
 

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.serve.comm import ServeCommPlan
 from repro.serve.engine import Request, ServeEngine
@@ -64,6 +65,7 @@ def main() -> None:
                          "full provision batch*ceil(max_len/page_size)+1)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M")

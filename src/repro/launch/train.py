@@ -20,6 +20,7 @@ import numpy as np
 from repro.checkpoint.io import latest_step, load_checkpoint, save_checkpoint
 from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import synthetic_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.schedule import cosine_schedule
 from repro.train.trainer import make_train_step, train_state_init
 
@@ -75,6 +76,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     mesh = build_mesh(args.mesh)

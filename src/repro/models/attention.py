@@ -1,12 +1,10 @@
 """Attention: GQA/MQA, causal + sliding-window masks, KV-cache decode.
 
-Two execution paths:
-
-* the XLA path (below) — used for CPU smoke tests and for every dry-run
-  compile (Pallas does not lower to the CPU backend);
-* the Pallas path (``repro.kernels.ops.flash_attention``) — the TPU-target
-  kernel, numerically validated against ``repro.kernels.ref`` in tests; the
-  model selects it with ``use_pallas=True`` on TPU.
+Attention is computed by the XLA code below on every backend. The Pallas
+flash-attention kernel (``repro.kernels.ops.flash_attention``, tested
+against ``repro.kernels.ref``) is not called by the model. The paged decode
+path gathers its pages with the Pallas kernel of
+:mod:`repro.kernels.paged_kv` on TPU.
 
 Decode supports two cache layouts:
 

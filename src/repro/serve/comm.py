@@ -63,8 +63,7 @@ class ServeComm:
 
     @property
     def size(self) -> int:
-        from repro.compat import axis_size
-        return axis_size(self.axis)
+        return jax.lax.axis_size(self.axis)
 
     def rank(self):
         return lax.axis_index(self.axis)

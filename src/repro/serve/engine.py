@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import set_mesh, shard_map
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import Sharder, batch_axes
 from repro.models.attention import KVCache, PagedKVCache, paged_splice
@@ -192,7 +191,7 @@ def _make_serve_step_comm(cfg: ModelConfig, mesh, comm_plan: ServeCommPlan,
             return select_tokens(logits, temps, key), new_cache
 
         cspec = serve_cache_specs(cache, tp, nshard, batch_axis=dpe)
-        f = shard_map(
+        f = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(serve_param_specs(cfg, params, tp), P(bd, None),
                       cspec, P(bd), P(bd), P()),
@@ -227,7 +226,7 @@ def _make_prefill_comm(cfg: ModelConfig, mesh, comm_plan: ServeCommPlan,
             return nxt, new_cache
 
         cspec = serve_cache_specs(cache, tp, nshard, batch_axis=dpe)
-        f = shard_map(
+        f = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(serve_param_specs(cfg, params, tp),
                       {"tokens": P(bd, None)},
@@ -393,7 +392,7 @@ class ServeEngine:
     def generate(self, requests: List[Request]) -> List[Request]:
         self._validate(requests)
         self.cache_bytes_resident = 0
-        ctx = (set_mesh(self.mesh) if self.mesh is not None
+        ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
         with ctx:
             if self._padded_ok:
@@ -693,7 +692,7 @@ class ServeEngine:
                 return nxt, DecodeCache(kv, None, cache.length)
 
             cspec = serve_cache_specs(cache, tp, 1)
-            f = shard_map(
+            f = jax.shard_map(
                 inner, mesh=mesh,
                 in_specs=(serve_param_specs(cfg, params, tp),
                           P(None, None), cspec, P(), P(), P(), P(), P()),

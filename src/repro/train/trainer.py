@@ -44,7 +44,6 @@ from repro.optim.adamw import (adamw_init, adamw_update,
                                bucket_decay_masks, sharded_adamw_init,
                                sharded_adamw_update)
 from repro.train.losses import total_loss
-from repro.compat import shard_map
 
 
 class TrainState(NamedTuple):
@@ -424,18 +423,18 @@ def make_train_step(
             dpe = dp_entry(dp)
             step_z1 = (inner_step_zero1_overlap if schedule == "overlap"
                        else inner_step_zero1)
-            f = shard_map(step_z1, mesh=mesh,
-                          in_specs=(state_spec, batch_spec,
-                                    tuple(P(dpe) for _ in masks)),
-                          out_specs=(state_spec, metric_specs),
-                          check_vma=False, axis_names=set(dp))
+            f = jax.shard_map(step_z1, mesh=mesh,
+                              in_specs=(state_spec, batch_spec,
+                                        tuple(P(dpe) for _ in masks)),
+                              out_specs=(state_spec, metric_specs),
+                              check_vma=False, axis_names=set(dp))
             return f(state, batch, masks)
         state_spec = jax.tree_util.tree_map(lambda _: P(), state)
         step_rep = inner_step_overlap if schedule == "overlap" else inner_step
-        f = shard_map(step_rep, mesh=mesh,
-                      in_specs=(state_spec, batch_spec),
-                      out_specs=(state_spec, metric_specs),
-                      check_vma=False, axis_names=set(dp))
+        f = jax.shard_map(step_rep, mesh=mesh,
+                          in_specs=(state_spec, batch_spec),
+                          out_specs=(state_spec, metric_specs),
+                          check_vma=False, axis_names=set(dp))
         return f(state, batch)
 
     return train_step

@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (
     get_comm_plan,
     plan_buckets,
@@ -24,6 +23,7 @@ from repro.core import (
     reduce_gradients,
 )
 from repro.core.bucketing import _pack_bucket_dma, pack_bucket, unpack_bucket
+from repro.kernels import bucket_pack
 from repro.kernels.bucket_pack import (
     arena_from_leaves,
     arena_layout,
@@ -120,6 +120,20 @@ class TestPallasKernels:
             off = int(arena_offs[i])
             got = out_k[off: off + leaf.size].reshape(leaf.shape)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(leaf))
+
+    @pytest.mark.parametrize("shapes", SHAPES)
+    def test_split_calls_match_oracle(self, shapes, monkeypatch):
+        """A buffer longer than one call's tile tables is written chunk by
+        chunk into one aliased output; every chunk lands in place."""
+        monkeypatch.setattr(bucket_pack, "MAX_TILES_PER_CALL", 2)
+        tree = _tree(shapes)
+        _, _, arena, _, unpack_table, _, arena_size = _plan_tables(tree, 2)
+        blk, val = unpack_table
+        out_k = bucket_unpack_pallas(arena, jnp.asarray(blk),
+                                     jnp.asarray(val), arena_size, tile=TILE,
+                                     interpret=True)
+        out_r = bucket_unpack_ref(arena, blk, val, arena_size, tile=TILE)
+        np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
     def test_dma_pack_matches_concat_pack(self):
         """The non-TPU DUS lowering == pack_bucket on slot-aligned plans."""
@@ -229,7 +243,6 @@ class TestPlanCacheTrainStep:
 
     @pytest.mark.parametrize("schedule", ["post", "overlap"])
     def test_repeated_steps_hit_then_knob_and_shape_miss(self, schedule):
-        from repro.compat import set_mesh
         from repro.configs import get_config
         from repro.data.pipeline import synthetic_batch
 
@@ -237,7 +250,7 @@ class TestPlanCacheTrainStep:
         cfg = get_config("olmo-1b-smoke")
         step, state = self._step_and_state(cfg, mesh, schedule=schedule)
         plan_cache_clear()
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # eager (unjitted) calls re-trace every step: each trace asks
             # for the plan again, so steps 2..3 must hit the cache.
             for i in range(3):
@@ -249,7 +262,7 @@ class TestPlanCacheTrainStep:
         # knob change: same tree, different num_streams -> new plan
         step3, state3 = self._step_and_state(cfg, mesh, schedule=schedule,
                                              num_streams=3)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step3(state3, synthetic_batch(cfg, 2, 16, seed=0))
         s = plan_cache_stats()
         assert s["misses"] == 2 and s["size"] == 2, s
@@ -257,7 +270,7 @@ class TestPlanCacheTrainStep:
         # shape change: different arch -> different grad shapes -> new plan
         cfg2 = get_config("gemma-2b-smoke")
         step_g, state_g = self._step_and_state(cfg2, mesh, schedule=schedule)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_g(state_g, synthetic_batch(cfg2, 2, 16, seed=0))
         s = plan_cache_stats()
         assert s["misses"] == 3 and s["builds"] == 3 and s["size"] == 3, s
@@ -265,7 +278,6 @@ class TestPlanCacheTrainStep:
     def test_schedules_key_separate_plans(self):
         """post and overlap must never share a cached plan: the overlap
         partition is contiguous-by-use-order, post is size-balanced."""
-        from repro.compat import set_mesh
         from repro.configs import get_config
         from repro.data.pipeline import synthetic_batch
 
@@ -274,7 +286,7 @@ class TestPlanCacheTrainStep:
         batch = synthetic_batch(cfg, 2, 16, seed=0)
         for schedule in ("post", "overlap"):
             step, state = self._step_and_state(cfg, mesh, schedule=schedule)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step(state, batch)
         s = plan_cache_stats()
         assert s["misses"] == 2 and s["builds"] == 2 and s["size"] == 2, s
@@ -301,8 +313,8 @@ class TestReducePaths:
             return reduce_gradients(rt, tr, cp, axis="data", mean=True,
                                     pack=pack, reduction=reduction)
 
-        f = jax.jit(shard_map(run, mesh=mesh, in_specs=(spec,),
-                              out_specs=spec, check_vma=False))
+        f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(spec,),
+                                  out_specs=spec, check_vma=False))
         got = f(tree)
         for g, e in zip(jax.tree_util.tree_leaves(got),
                         jax.tree_util.tree_leaves(tree)):

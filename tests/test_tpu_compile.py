@@ -1,0 +1,118 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Nothing runs: each kernel is lowered from shapes and compiled for a
+described (not attached) ``v5e:2x2`` topology, which catches what interpret
+mode cannot (block shapes off the (8, 128) tiling, scalar-prefetch tables
+larger than SMEM). The topology is described inside a fixture, never while
+this module is imported: only one process at a time may load the TPU
+library, and pytest-xdist workers import every test file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.bucket_pack import (MAX_TILES_PER_CALL, TILE,
+                                       bucket_pack_pallas,
+                                       bucket_unpack_pallas)
+from repro.kernels.ops import flash_attention, row_gather, ssd_chunked
+from repro.kernels.paged_kv import paged_gather_pallas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep it out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def compile_text(fn, sharding, *shapes) -> str:
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_gather(one_chip, dtype):
+    # one layer of the olmo-1b pool a batch-4 engine holds at max_len 320
+    cfg = get_config("olmo-1b")
+    ps, maxp, b = 16, 20, 4
+    pool = (1 + b * maxp, ps, cfg.num_kv_heads, cfg.head_dim)
+    hlo = compile_text(paged_gather_pallas, one_chip, (pool, dtype),
+                       ((b, maxp), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("kernel", [bucket_pack_pallas, bucket_unpack_pallas])
+def test_bucket_pack_unpack(one_chip, kernel):
+    # the unpack over a 4-layer olmo-1b gradient arena: 363,520 tiles, whose
+    # tables outgrow SMEM unless the call is split
+    n = 363_520 * TILE
+    assert n // TILE > MAX_TILES_PER_CALL
+
+    def fn(src, block, valid):
+        return kernel(src, block, valid, n, tile=TILE)
+
+    hlo = compile_text(fn, one_chip, ((n,), jnp.float32),
+                       ((n // TILE,), jnp.int32), ((n // TILE,), jnp.int32))
+    assert hlo.count("tpu_custom_call") >= -(-n // TILE // MAX_TILES_PER_CALL)
+
+
+def test_ssd_chunked(one_chip):
+    cfg = get_config("mamba2-780m")
+    c = cfg.ssm
+    b, s = 1, 2 * c.chunk_size
+    h, p = c.num_heads(cfg.d_model), c.head_dim
+    g, n = c.ngroups, c.d_state
+    fn = functools.partial(ssd_chunked, chunk=c.chunk_size, interpret=False)
+    hlo = compile_text(fn, one_chip, ((b, s, h, p), jnp.bfloat16),
+                       ((b, s, h), jnp.float32), ((h,), jnp.float32),
+                       ((b, s, g, n), jnp.bfloat16),
+                       ((b, s, g, n), jnp.bfloat16))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_attention(one_chip):
+    cfg = get_config("olmo-1b")
+    q = (1, cfg.num_heads, 2048, cfg.head_dim)
+    kv = (1, cfg.num_kv_heads, 2048, cfg.head_dim)
+    fn = functools.partial(flash_attention, interpret=False)
+    hlo = compile_text(fn, one_chip, (q, jnp.bfloat16), (kv, jnp.bfloat16),
+                       (kv, jnp.bfloat16))
+    assert "tpu_custom_call" in hlo
+
+
+def test_row_gather(one_chip):
+    # mixtral-8x22b token rows into top-2 expert slots
+    cfg = get_config("mixtral-8x22b")
+    t = 4096
+    fn = functools.partial(row_gather, interpret=False)
+    hlo = compile_text(fn, one_chip, ((t, cfg.d_model), jnp.bfloat16),
+                       ((2 * t,), jnp.int32))
+    assert "tpu_custom_call" in hlo
