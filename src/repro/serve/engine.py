@@ -43,6 +43,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -399,7 +400,8 @@ class ServeEngine:
                 pending = list(requests)
                 while pending:
                     batch = self._take_batch(pending)
-                    self._run_continuous(batch, pending)
+                    with TraceAnnotation("serve.batch"):
+                        self._run_continuous(batch, pending)
             else:
                 # grouped fallback: equal prompt lengths per batch
                 groups: Dict[int, List[Request]] = {}
@@ -407,7 +409,8 @@ class ServeEngine:
                     groups.setdefault(int(r.prompt.shape[-1]), []).append(r)
                 for _, rs in sorted(groups.items()):
                     for i in range(0, len(rs), self.batch_size):
-                        self._run_grouped(rs[i: i + self.batch_size])
+                        with TraceAnnotation("serve.batch"):
+                            self._run_grouped(rs[i: i + self.batch_size])
         return requests
 
     # -- batch formation -------------------------------------------------
@@ -442,6 +445,10 @@ class ServeEngine:
         return batch
 
     # -- continuous (left-padded) path ------------------------------------
+    # Host spans (``jax.profiler.TraceAnnotation``, ``serve.*``) mark each
+    # piece of the loop on the profiler's clock, so a trace tells which one
+    # held the device idle; ``serve.sync`` marks every device-to-host read.
+    # Without a profiler recording, a span costs about a microsecond.
     def _run_continuous(self, batch: List[Request],
                         pending: List[Request]) -> None:
         cfg = self.cfg
@@ -461,25 +468,28 @@ class ServeEngine:
         temps = np.asarray([self._temp_of(s.req) if s.req else 0.0
                             for s in slots], np.float32)
         reserved: Dict[int, int] = {}  # slot -> worst-case page span
-        if self._paged:
-            cache = init_paged_cache(cfg, B, self.max_len, page_size=PS,
-                                     num_pages=self._num_pages,
-                                     dtype=self._cache_dtype)
-            self._owner = page_state_init(self._num_pages, B,
-                                          self._max_pages).owner
-            for i, s in enumerate(slots):
-                if s.req is None:
-                    continue  # empty slot: writes land in the trash page
-                cache = self._palloc(cache, i, int(start[i]) // PS,
-                                     (pad - 1) // PS)
-                reserved[i] = pages_for_span(
-                    int(start[i]), pad + s.req.max_new_tokens, PS)
-        else:
-            cache = init_cache(cfg, B, self.max_len, dtype=self._cache_dtype)
-        self._note_cache(cache)
-        nxt, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(tokens)}, cache,
-            jnp.asarray(start), jnp.asarray(temps), self._next_key())
+        with TraceAnnotation("serve.pool_init"):
+            if self._paged:
+                cache = init_paged_cache(cfg, B, self.max_len, page_size=PS,
+                                         num_pages=self._num_pages,
+                                         dtype=self._cache_dtype)
+                self._owner = page_state_init(self._num_pages, B,
+                                              self._max_pages).owner
+                for i, s in enumerate(slots):
+                    if s.req is None:
+                        continue  # empty slot: writes land in the trash page
+                    cache = self._palloc(cache, i, int(start[i]) // PS,
+                                         (pad - 1) // PS)
+                    reserved[i] = pages_for_span(
+                        int(start[i]), pad + s.req.max_new_tokens, PS)
+            else:
+                cache = init_cache(cfg, B, self.max_len,
+                                   dtype=self._cache_dtype)
+            self._note_cache(cache)
+        with TraceAnnotation("serve.prefill"):
+            nxt, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(tokens)}, cache,
+                jnp.asarray(start), jnp.asarray(temps), self._next_key())
         cur = pad
 
         def record(s: _Slot, t: int) -> None:
@@ -504,12 +514,14 @@ class ServeEngine:
             return self._with_table(cache, st.table)
 
         while True:
-            toks = np.array(nxt)  # copy: admission may overwrite a row
+            with TraceAnnotation("serve.sync"):
+                toks = np.array(nxt)  # copy: admission may overwrite a row
             admitted = False
-            for i, s in enumerate(slots):
-                if not s.done and s.req is not None:
-                    record(s, int(toks[i, 0]))
-                    cache = reclaim(i, s, cache)
+            with TraceAnnotation("serve.record"):
+                for i, s in enumerate(slots):
+                    if not s.done and s.req is not None:
+                        record(s, int(toks[i, 0]))
+                        cache = reclaim(i, s, cache)
             # early slot recycling: prefill the next request into a finished
             # slot just below the shared cursor (start masks older rows)
             if self._can_admit and pending:
@@ -521,23 +533,22 @@ class ServeEngine:
                         continue
                     r = pending.pop(j)
                     plen = int(r.prompt.shape[-1])
-                    if self._paged:
-                        cache = self._palloc(cache, i, (cur - plen) // PS,
-                                             (cur - 1) // PS)
-                        reserved[i] = pages_for_span(
-                            cur - plen, cur + r.max_new_tokens, PS)
-                    tok0, cache = self._admit(r, cache, i, cur)
-                    s.activate(r)
-                    start[i] = cur - plen
-                    temps[i] = self._temp_of(r)
-                    toks[i, 0] = tok0
-                    record(s, tok0)  # the admission prefill's first token
-                    cache = reclaim(i, s, cache)
+                    with TraceAnnotation("serve.admit"):
+                        if self._paged:
+                            cache = self._palloc(cache, i, (cur - plen) // PS,
+                                                 (cur - 1) // PS)
+                            reserved[i] = pages_for_span(
+                                cur - plen, cur + r.max_new_tokens, PS)
+                        tok0, cache = self._admit(r, cache, i, cur)
+                        s.activate(r)
+                        start[i] = cur - plen
+                        temps[i] = self._temp_of(r)
+                        toks[i, 0] = tok0
+                        record(s, tok0)  # the admission prefill's first token
+                        cache = reclaim(i, s, cache)
                     admitted = True
             if all(s.done or s.req is None for s in slots):
                 break
-            if admitted:
-                nxt = jnp.asarray(toks)
             if cur >= self.max_len:  # defensive: budgets guarantee this
                 for s in slots:      # never trips (validated runways)
                     if not s.done:
@@ -548,19 +559,25 @@ class ServeEngine:
                 # live slot gets one (reservation makes this infallible)
                 act = [i for i, s in enumerate(slots) if not s.done]
                 if act:
-                    st, ok = alloc_step_pages_jit(
-                        PageState(cache.kv.table, self._owner),
-                        jnp.asarray(act, jnp.int32),
-                        jnp.asarray(cur // PS, jnp.int32))
-                    if not bool(ok):  # reservations make this unreachable
-                        raise RuntimeError(
-                            "page pool exhausted at the decode boundary — "
-                            "reservation accounting broken")
-                    self._owner = st.owner
-                    cache = self._with_table(cache, st.table)
-            nxt, cache = self._step(self.params, nxt, cache,
-                                    jnp.asarray(start), jnp.asarray(temps),
-                                    self._next_key())
+                    with TraceAnnotation("serve.page_alloc"):
+                        st, ok = alloc_step_pages_jit(
+                            PageState(cache.kv.table, self._owner),
+                            jnp.asarray(act, jnp.int32),
+                            jnp.asarray(cur // PS, jnp.int32))
+                        with TraceAnnotation("serve.sync"):
+                            ok = bool(ok)
+                        if not ok:  # reservations make this unreachable
+                            raise RuntimeError(
+                                "page pool exhausted at the decode boundary "
+                                "— reservation accounting broken")
+                        self._owner = st.owner
+                        cache = self._with_table(cache, st.table)
+            with TraceAnnotation("serve.dispatch"):
+                if admitted:
+                    nxt = jnp.asarray(toks)
+                nxt, cache = self._step(self.params, nxt, cache,
+                                        jnp.asarray(start),
+                                        jnp.asarray(temps), self._next_key())
             cur += 1
 
     def _admittable(self, pending: List[Request], cur: int,
@@ -592,15 +609,18 @@ class ServeEngine:
     def _palloc(self, cache: DecodeCache, slot: int, lo_page: int,
                 hi_page: int) -> DecodeCache:
         """Map fresh pool pages at ``slot``'s logical pages [lo, hi]."""
-        logical = jnp.arange(lo_page, hi_page + 1, dtype=jnp.int32)
-        st, ok = alloc_slot_pages_jit(
-            PageState(cache.kv.table, self._owner),
-            jnp.asarray(slot, jnp.int32), logical)
-        if not bool(ok):  # reservations make this unreachable
-            raise RuntimeError("page pool exhausted at prefill/admission — "
-                               "reservation accounting broken")
-        self._owner = st.owner
-        return self._with_table(cache, st.table)
+        with TraceAnnotation("serve.page_alloc"):
+            logical = jnp.arange(lo_page, hi_page + 1, dtype=jnp.int32)
+            st, ok = alloc_slot_pages_jit(
+                PageState(cache.kv.table, self._owner),
+                jnp.asarray(slot, jnp.int32), logical)
+            with TraceAnnotation("serve.sync"):
+                ok = bool(ok)
+            if not ok:  # reservations make this unreachable
+                raise RuntimeError("page pool exhausted at prefill/admission "
+                                   "— reservation accounting broken")
+            self._owner = st.owner
+            return self._with_table(cache, st.table)
 
     def _admit(self, r: Request, cache, slot: int, cur: int):
         """Prefill ``r`` alone and splice its KV rows into ``slot``'s cache
@@ -621,7 +641,9 @@ class ServeEngine:
                         jnp.asarray([p_adm - plen], jnp.int32),
                         jnp.asarray([self._temp_of(r)], jnp.float32),
                         self._next_key())
-        return int(np.asarray(nxt)[0, 0]), cache
+        with TraceAnnotation("serve.sync"):
+            tok0 = int(np.asarray(nxt)[0, 0])
+        return tok0, cache
 
     def _admit_fn(self, p_adm: int):
         """Jitted single-request admission prefill, cached per padded
@@ -718,7 +740,8 @@ class ServeEngine:
             self.params, {"tokens": jnp.asarray(prompts)}, cache, start,
             jnp.asarray(temps), self._next_key())
         text = cfg.modality == "text"
-        gen = [np.asarray(nxt)]
+        with TraceAnnotation("serve.sync"):
+            gen = [np.asarray(nxt)]
         stopped = [False] * b
 
         def update_stops():
@@ -732,9 +755,11 @@ class ServeEngine:
         update_stops()
         while any(not stopped[i] and len(gen) < r.max_new_tokens
                   for i, r in enumerate(reqs)):
-            nxt, cache = self._step(self.params, nxt, cache, start,
-                                    jnp.asarray(temps), self._next_key())
-            gen.append(np.asarray(nxt))
+            with TraceAnnotation("serve.dispatch"):
+                nxt, cache = self._step(self.params, nxt, cache, start,
+                                        jnp.asarray(temps), self._next_key())
+            with TraceAnnotation("serve.sync"):
+                gen.append(np.asarray(nxt))
             update_stops()
         toks = np.concatenate(gen, axis=-1)  # (B,steps) or (B,K,steps)
         for i, r in enumerate(reqs):
