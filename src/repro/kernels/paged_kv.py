@@ -1,21 +1,23 @@
 """Paged KV-cache page gather — Pallas TPU (scalar prefetch).
 
 The paged serve cache stores K/V in a fixed pool of fixed-size pages
-(``(num_pages, page_size, KV, hd)`` per layer) with a per-slot page table;
-attention needs each slot's pages laid out contiguously in sequence order.
+(``(L, num_pages, page_size, KV, hd)``, stacked over layers) with a per-slot
+page table shared by every layer; attention needs one layer's pages of each
+slot laid out contiguously in sequence order.
 This is the same shape of problem as the gradient-bucket pack
 (`repro.kernels.bucket_pack`): a table-driven tile gather whose index
 tables are known outside the kernel. The TPU kernel DMAs one pool page per
-grid step straight to its destination row, driven by the prefetched page
-table — unmapped entries (``-1``, pad prefix / freed slots) emit zeros.
+grid step straight to its destination row, driven by the prefetched layer
+index and page table — unmapped entries (``-1``, pad prefix / freed slots)
+emit zeros.
 
 Three equivalent implementations, mirroring the bucket-pack layering:
 
 * :func:`paged_gather_pallas` — the TPU scalar-prefetch kernel
   (interpret-mode tested on CPU);
-* :func:`paged_gather_take`   — the vectorized ``jnp.take`` lowering used
+* :func:`paged_gather_take`   — the vectorized one-gather lowering used
   on backends without a Pallas TPU pipeline (XLA:CPU scalarizes nothing
-  here — it is one gather);
+  here);
 * :func:`paged_gather_ref`    — scalar oracle for the kernel tests.
 
 :func:`paged_gather` dispatches on the backend; the model code calls only
@@ -32,61 +34,72 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(table_ref, pool_ref, out_ref):
+def _kernel(layer_ref, table_ref, pool_ref, out_ref):
     t = pl.program_id(0)
     mapped = table_ref[t] >= 0
-    out_ref[...] = jnp.where(mapped, pool_ref[...],
-                             jnp.zeros_like(pool_ref[...]))
+    page = pool_ref[...]                              # (PS, KV, hd)
+    page = jnp.where(mapped, page, jnp.zeros_like(page))
+    out_ref[...] = page.reshape(out_ref.shape)
 
 
-def paged_gather_pallas(pool: jax.Array, table: jax.Array, *,
+def paged_gather_pallas(pool: jax.Array, table: jax.Array, layer, *,
                         interpret: bool = False) -> jax.Array:
-    """pool: (NP, PS, KV, hd) one layer's page pool; table: (B, MAXP) int32
-    pool page ids (-1 unmapped). Returns (B, MAXP*PS, KV, hd) — slot b's
-    pages in logical order, unmapped pages zero-filled.
+    """pool: (L, NP, PS, KV, hd) the layer-stacked page pool; table:
+    (B, MAXP) int32 pool page ids (-1 unmapped); layer: () int32 which
+    layer's pages to read. Returns (B, MAXP*PS, KV, hd) — slot b's pages
+    of that layer in logical order, unmapped pages zero-filled.
 
     Grid = one destination page per step; the BlockSpec index_map consumes
-    the prefetched (flattened) table so each step DMAs exactly one pool
-    page (clamped to 0 for unmapped entries, zeroed in the kernel body).
+    the prefetched layer index and (flattened) table so each step DMAs
+    exactly one page of the stacked pool (clamped to page 0 for unmapped
+    entries, zeroed in the kernel body). The pool is read where it lies:
+    no slice of the layer and no reshape of the pool; the kernel body lays
+    each ``(PS, KV, hd)`` page out as the view's ``(PS, KV*hd)`` row.
     """
     b, maxp = table.shape
-    np_, ps = pool.shape[0], pool.shape[1]
-    tail = pool.shape[2:]
+    ps = pool.shape[2]
+    tail = pool.shape[3:]
+    e = tail[0] * tail[1]
     flat_table = table.reshape(-1)
-    pool2 = pool.reshape(np_, ps, -1)
-    e = pool2.shape[-1]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b * maxp,),
         in_specs=[
-            pl.BlockSpec((1, ps, e),
-                         lambda t, table_ref: (jnp.maximum(table_ref[t], 0),
-                                               0, 0)),
+            pl.BlockSpec((None, None, ps) + tail,
+                         lambda t, layer_ref, table_ref: (
+                             layer_ref[0], jnp.maximum(table_ref[t], 0),
+                             0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, ps, e), lambda t, table_ref: (t, 0, 0)),
+        out_specs=pl.BlockSpec((None, ps, e),
+                               lambda t, layer_ref, table_ref: (t, 0, 0)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * maxp, ps, e), pool.dtype),
         interpret=interpret,
-    )(flat_table, pool2)
+    )(layer, flat_table, pool)
     return out.reshape((b, maxp * ps) + tail)
 
 
-def paged_gather_take(pool: jax.Array, table: jax.Array) -> jax.Array:
-    """Vectorized lowering: ONE row gather of the pool's pages plus an
-    unmapped-page mask — numerically identical to the kernel."""
+def paged_gather_take(pool: jax.Array, table: jax.Array, layer
+                      ) -> jax.Array:
+    """Vectorized lowering: ONE row gather of the layer's pages from the
+    stacked pool plus an unmapped-page mask — numerically identical to the
+    kernel."""
     b, maxp = table.shape
-    ps = pool.shape[1]
-    pages = jnp.take(pool, jnp.clip(table, 0, pool.shape[0] - 1), axis=0)
+    ps = pool.shape[2]
+    ids = jnp.clip(table, 0, pool.shape[1] - 1)
+    pages = pool[layer, ids]                          # (B, MAXP, PS, KV, hd)
     mapped = (table >= 0).reshape(b, maxp, 1, 1, 1)
     pages = jnp.where(mapped, pages, jnp.zeros((), pool.dtype))
-    return pages.reshape((b, maxp * ps) + pool.shape[2:])
+    return pages.reshape((b, maxp * ps) + pool.shape[3:])
 
 
 def paged_gather_ref(pool, table) -> jax.Array:
-    """Scalar jnp oracle for the interpret-mode kernel tests."""
+    """Scalar jnp oracle for the interpret-mode kernel tests; ``pool`` is
+    one layer's ``(NP, PS, KV, hd)`` pool."""
     b, maxp = table.shape
     ps = pool.shape[1]
     rows = []
@@ -105,9 +118,9 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:
-    """Backend dispatch: Pallas tile-gather on TPU, one-gather jnp.take
+def paged_gather(pool: jax.Array, table: jax.Array, layer) -> jax.Array:
+    """Backend dispatch: Pallas tile-gather on TPU, the one-gather
     lowering elsewhere (the CPU smoke/conformance path)."""
     if _on_tpu():
-        return paged_gather_pallas(pool, table)
-    return paged_gather_take(pool, table)
+        return paged_gather_pallas(pool, table, layer)
+    return paged_gather_take(pool, table, layer)

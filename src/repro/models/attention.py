@@ -195,8 +195,8 @@ class PagedKVCache:
     layouts are token-identical by construction.
 
     The table is shared by every layer (one allocation covers the whole
-    stack); ``page_size`` is static metadata so caches scan over the layer
-    axis. Allocation lives in :mod:`repro.serve.paging`.
+    stack); ``page_size`` is static metadata so the pool can ride in a
+    layer scan's carry. Allocation lives in :mod:`repro.serve.paging`.
     """
 
     def __init__(self, k, v, table, length, page_size: int):
@@ -216,19 +216,26 @@ class PagedKVCache:
 
 @jax.tree_util.register_pytree_node_class
 class PagedKVLayer:
-    """One layer's view of a :class:`PagedKVCache` (pool slice + the shared
-    table/cursor) — what the per-layer block code sees in place of a
-    :class:`KVCache`."""
+    """Layer ``layer``'s view of a :class:`PagedKVCache` — what the
+    per-layer block code sees in place of a :class:`KVCache`.
 
-    def __init__(self, k, v, table, length, page_size: int):
-        self.k = k                # (NP, PS, KV, hd)
+    It names the whole layer-stacked pool and a layer index (a traced
+    scalar inside the layer scan, a Python int in the unrolled stacks):
+    writes scatter into the stacked pool and the gather reads it where it
+    lies, so the layer loop carries one pool buffer that XLA updates in
+    place, and no layer's pool is ever sliced out or copied."""
+
+    def __init__(self, k, v, table, length, layer, page_size: int):
+        self.k = k                # (L, NP, PS, KV, hd) — the stacked pool
         self.v = v
         self.table = table        # (B, MAXP) int32
         self.length = length      # () int32
+        self.layer = layer        # () int32 — which layer of the pool
         self.page_size = int(page_size)
 
     def tree_flatten(self):
-        return (self.k, self.v, self.table, self.length), self.page_size
+        return ((self.k, self.v, self.table, self.length, self.layer),
+                self.page_size)
 
     @classmethod
     def tree_unflatten(cls, page_size, children):
@@ -245,28 +252,30 @@ def _paged_write_ids(table, pos, page_size):
 def paged_update_decode(layer: PagedKVLayer, k_new, v_new) -> PagedKVLayer:
     """Append ONE token (k_new/v_new: (B,1,KVn,hd)) at the shared cursor.
 
-    Every slot writes pool page ``table[b, cur // PS]`` at in-page offset
-    ``cur % PS`` — distinct pages by the allocator's unique-ownership
+    Every slot writes row ``[layer, table[b, cur // PS], cur % PS]`` of the
+    stacked pool — distinct pages by the allocator's unique-ownership
     invariant, so the scatter never collides (except in the trash page,
     whose content is never read)."""
     ps = layer.page_size
-    k_new = _expand_heads(k_new, layer.k.shape[2])
-    v_new = _expand_heads(v_new, layer.k.shape[2])
+    k_new = _expand_heads(k_new, layer.k.shape[3])
+    v_new = _expand_heads(v_new, layer.k.shape[3])
     pos = layer.length
     ids = _paged_write_ids(layer.table, pos[None], ps)[:, 0]  # (B,)
     off = pos % ps
-    k = layer.k.at[ids, off].set(k_new[:, 0].astype(layer.k.dtype))
-    v = layer.v.at[ids, off].set(v_new[:, 0].astype(layer.v.dtype))
-    return PagedKVLayer(k, v, layer.table, layer.length + 1, ps)
+    l = layer.layer
+    k = layer.k.at[l, ids, off].set(k_new[:, 0].astype(layer.k.dtype))
+    v = layer.v.at[l, ids, off].set(v_new[:, 0].astype(layer.v.dtype))
+    return PagedKVLayer(k, v, layer.table, layer.length + 1, l, ps)
 
 
 def paged_prefill_update(layer: PagedKVLayer, k_new, v_new) -> PagedKVLayer:
     """Write a fresh prefill (k_new/v_new: (B,S,KVn,hd)) at positions
-    ``[0, S)`` — whole pages scattered into the pool; positions whose pages
-    are unmapped (each slot's left-pad prefix) go to the trash page."""
+    ``[0, S)`` — whole pages scattered into the layer's pages of the
+    stacked pool; positions whose pages are unmapped (each slot's left-pad
+    prefix) go to the trash page."""
     ps = layer.page_size
-    k_new = _expand_heads(k_new, layer.k.shape[2])
-    v_new = _expand_heads(v_new, layer.k.shape[2])
+    k_new = _expand_heads(k_new, layer.k.shape[3])
+    v_new = _expand_heads(v_new, layer.k.shape[3])
     b, s = k_new.shape[:2]
     npg = -(-s // ps)
     pad = npg * ps - s
@@ -277,9 +286,10 @@ def paged_prefill_update(layer: PagedKVLayer, k_new, v_new) -> PagedKVLayer:
     vp = v_new.reshape((b, npg, ps) + v_new.shape[2:])
     ids = layer.table[:, :npg]
     ids = jnp.where(ids >= 0, ids, 0)                 # (B, npg)
-    k = layer.k.at[ids].set(kp.astype(layer.k.dtype))
-    v = layer.v.at[ids].set(vp.astype(layer.v.dtype))
-    return PagedKVLayer(k, v, layer.table, layer.length + s, ps)
+    l = layer.layer
+    k = layer.k.at[l, ids].set(kp.astype(layer.k.dtype))
+    v = layer.v.at[l, ids].set(vp.astype(layer.v.dtype))
+    return PagedKVLayer(k, v, layer.table, layer.length + s, l, ps)
 
 
 def paged_splice(cache: PagedKVCache, slot, dest, k_rows, v_rows
@@ -307,14 +317,14 @@ def paged_splice(cache: PagedKVCache, slot, dest, k_rows, v_rows
 def paged_decode_attention(cfg: ModelConfig, q, layer: PagedKVLayer,
                            start: Optional[jax.Array] = None) -> jax.Array:
     """One-token attention against the paged cache: gather each slot's
-    pages into sequence order (Pallas tile-gather on TPU, one jnp.take
-    elsewhere — :mod:`repro.kernels.paged_kv`), then the standard masked
+    pages of this layer into sequence order (Pallas tile-gather on TPU, one
+    gather elsewhere — :mod:`repro.kernels.paged_kv`), then the standard masked
     decode attention. Validity is identical to the contiguous layout —
     ``[start, length)`` — which is what makes paged-vs-contiguous token
     equality exact rather than approximate."""
     from repro.kernels.paged_kv import paged_gather
-    k_view = paged_gather(layer.k, layer.table)
-    v_view = paged_gather(layer.v, layer.table)
+    k_view = paged_gather(layer.k, layer.table, layer.layer)
+    v_view = paged_gather(layer.v, layer.table, layer.layer)
     view = KVCache(k_view, v_view, layer.length, ring=False)
     return decode_attention(cfg, q, view, start=start)
 

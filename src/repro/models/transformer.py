@@ -279,21 +279,30 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
 # ---------------------------------------------------------------------------
 
 def _layer_kv(kv, l: int):
-    """Layer ``l``'s view of a stacked (contiguous or paged) KV cache."""
+    """Layer ``l``'s view of a stacked (contiguous or paged) KV cache; the
+    paged view names the whole pool and the layer."""
     if isinstance(kv, PagedKVCache):
-        return PagedKVLayer(kv.k[l], kv.v[l], kv.table, kv.length,
-                            kv.page_size)
+        return PagedKVLayer(kv.k, kv.v, kv.table, kv.length, l, kv.page_size)
     return KVCache(kv.k[l], kv.v[l], kv.length, kv.ring)
 
 
-def _restack_kv(kv, ks, vs, advanced: int):
-    """Stack per-layer outputs back into the cache's layout; ``advanced`` is
-    how many tokens the cursor moved (S for prefill, 1 for decode)."""
-    if isinstance(kv, PagedKVCache):
-        return PagedKVCache(jnp.stack(ks), jnp.stack(vs), kv.table,
-                            kv.length + advanced, kv.page_size)
-    return KVCache(jnp.stack(ks), jnp.stack(vs), kv.length + advanced,
+def _set_layer_kv(kv, l: int, new):
+    """The stacked cache with layer ``l``'s updated view written back; the
+    cursor stays where it was (it moves once, after the last layer)."""
+    if isinstance(kv, PagedKVCache):  # the view's pool already holds it
+        return PagedKVCache(new.k, new.v, kv.table, kv.length, kv.page_size)
+    return KVCache(kv.k.at[l].set(new.k), kv.v.at[l].set(new.v), kv.length,
                    kv.ring)
+
+
+def _advance_kv(kv, advanced: int):
+    """``kv`` with its cursor moved ``advanced`` tokens (S for prefill, 1 for
+    decode)."""
+    if isinstance(kv, PagedKVCache):
+        return PagedKVCache(kv.k, kv.v, kv.table, kv.length + advanced,
+                            kv.page_size)
+    return KVCache(kv.k, kv.v, kv.length + advanced, kv.ring)
+
 
 def _attn_apply(cfg: ModelConfig, x, p, positions, shard,
                 kv: Optional[KVCache] = None, decode: bool = False,
@@ -527,7 +536,7 @@ class Model:
                                              start)
         if cache is not None and isinstance(cache.kv, PagedKVCache):
             return self._attn_stack_paged(params, x, positions, cache, remat,
-                                          start=start)
+                                          decode=False, start=start)
 
         def body(carry, scanned):
             x = carry
@@ -559,32 +568,35 @@ class Model:
         aux = {"load_balance": aux_v[:, 0].sum(), "router_z": aux_v[:, 1].sum()}
         return x, aux, new_cache
 
-    def _attn_stack_paged(self, params, x, positions, cache, remat,
-                          start=None):
-        """Prefill into the paged pool: the pool slices scan over the layer
-        axis; the page table and write cursor are shared by every layer."""
+    def _attn_stack_paged(self, params, x, positions, cache, remat, *,
+                          decode: bool, start=None):
+        """The layer scan over the paged pool, for prefill and decode: the
+        stacked K/V pools ride in the carry beside ``x``, each layer
+        scatters its rows into them and the page gather reads them where
+        they lie, so XLA keeps one pool buffer and updates it in place. The
+        page table and write cursor are shared by every layer."""
         cfg = self.cfg
         pk = cache.kv
 
         def body(carry, scanned):
-            x = carry
-            lp, kl, vl = scanned
-            layer = PagedKVLayer(kl, vl, pk.table, pk.length, pk.page_size)
+            x, k, v = carry
+            lp, l = scanned
+            layer = PagedKVLayer(k, v, pk.table, pk.length, l, pk.page_size)
             x, new_kv, aux = _dense_block(cfg, x, lp, positions, self.shard,
-                                          kv=layer, decode=False,
+                                          kv=layer, decode=decode,
                                           comm=self.comm, start=start)
             aux_vec = jnp.stack([aux.get("load_balance", jnp.zeros(())),
                                  aux.get("router_z", jnp.zeros(()))])
-            return x, (new_kv.k, new_kv.v, aux_vec)
+            return (x, new_kv.k, new_kv.v), aux_vec
 
         if remat:
             body = jax.checkpoint(body, policy=_remat_policy(cfg))
-        x, (k_out, v_out, aux_v) = jax.lax.scan(
-            body, x, (params["layers"], pk.k, pk.v))
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, k, v), aux_v = jax.lax.scan(body, (x, pk.k, pk.v),
+                                        (params["layers"], layers))
         s_new = x.shape[1]
         new_cache = DecodeCache(
-            PagedKVCache(k_out, v_out, pk.table, pk.length + s_new,
-                         pk.page_size),
+            PagedKVCache(k, v, pk.table, pk.length + s_new, pk.page_size),
             None, cache.length + s_new)
         aux = {"load_balance": aux_v[:, 0].sum(), "router_z": aux_v[:, 1].sum()}
         return x, aux, new_cache
@@ -593,26 +605,22 @@ class Model:
         """Python-loop layer stack for the comm (VCI-stream) serve path."""
         cfg = self.cfg
         take = jax.tree_util.tree_map
-        ks, vs = [], []
+        kv = None if cache is None else cache.kv
         lb = rz = jnp.zeros(())
         for l in range(cfg.num_layers):
             lp = take(lambda a: a[l], params["layers"])
-            kv = None
-            if cache is not None:
-                kv = _layer_kv(cache.kv, l)
-            x, new_kv, aux = _dense_block(cfg, x, lp, positions, None,
-                                          kv=kv, decode=False,
-                                          comm=self.comm, start=start)
-            if new_kv is not None:
-                ks.append(new_kv.k)
-                vs.append(new_kv.v)
+            x, new_kv, aux = _dense_block(
+                cfg, x, lp, positions, None,
+                kv=None if kv is None else _layer_kv(kv, l), decode=False,
+                comm=self.comm, start=start)
+            if kv is not None:
+                kv = _set_layer_kv(kv, l, new_kv)
             lb = lb + aux.get("load_balance", jnp.zeros(()))
             rz = rz + aux.get("router_z", jnp.zeros(()))
         new_cache = None
         if cache is not None:
-            new_cache = DecodeCache(
-                _restack_kv(cache.kv, ks, vs, x.shape[1]),
-                None, cache.length + x.shape[1])
+            new_cache = DecodeCache(_advance_kv(kv, x.shape[1]), None,
+                                    cache.length + x.shape[1])
         return x, {"load_balance": lb, "router_z": rz}, new_cache
 
     def _ssm_stack(self, params, x, positions, cache, remat):
@@ -743,39 +751,20 @@ class Model:
         cfg = self.cfg
         if self.comm is not None:  # unrolled: see _attn_stack_unrolled
             take = jax.tree_util.tree_map
-            ks, vs = [], []
+            kv = cache.kv
             for l in range(cfg.num_layers):
                 lp = take(lambda a: a[l], params["layers"])
-                kv = _layer_kv(cache.kv, l)
                 x, new_kv, _ = _dense_block(cfg, x, lp, positions, None,
-                                            kv=kv, decode=True,
+                                            kv=_layer_kv(kv, l), decode=True,
                                             comm=self.comm, start=start)
-                ks.append(new_kv.k)
-                vs.append(new_kv.v)
-            new_cache = DecodeCache(_restack_kv(cache.kv, ks, vs, 1),
-                                    None, cache.length + 1)
+                kv = _set_layer_kv(kv, l, new_kv)
+            new_cache = DecodeCache(_advance_kv(kv, 1), None,
+                                    cache.length + 1)
             return x, new_cache
 
         if isinstance(cache.kv, PagedKVCache):
-            pk = cache.kv
-
-            def paged_body(carry, scanned):
-                x = carry
-                lp, kl, vl = scanned
-                layer = PagedKVLayer(kl, vl, pk.table, pk.length,
-                                     pk.page_size)
-                x, new_kv, _ = _dense_block(cfg, x, lp, positions,
-                                            self.shard, kv=layer,
-                                            decode=True, comm=self.comm,
-                                            start=start)
-                return x, (new_kv.k, new_kv.v)
-
-            x, (k_out, v_out) = jax.lax.scan(
-                paged_body, x, (params["layers"], pk.k, pk.v))
-            new_cache = DecodeCache(
-                PagedKVCache(k_out, v_out, pk.table, pk.length + 1,
-                             pk.page_size),
-                None, cache.length + 1)
+            x, _, new_cache = self._attn_stack_paged(
+                params, x, positions, cache, False, decode=True, start=start)
             return x, new_cache
 
         def body(carry, scanned):

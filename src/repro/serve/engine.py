@@ -319,7 +319,10 @@ class ServeEngine:
                                       progress=progress,
                                       token_impl=token_impl)
         self.comm_plan = comm_plan
-        self._prefill = jax.jit(make_prefill(cfg, mesh, comm_plan))
+        # both programs take the cache donated: the paged pool is written
+        # in place, never copied whole
+        self._prefill = jax.jit(make_prefill(cfg, mesh, comm_plan),
+                                donate_argnums=(2,))
         self._step = jax.jit(make_serve_step(cfg, mesh, comm_plan),
                              donate_argnums=(2,))
         self._admit_fns: Dict[int, Callable] = {}

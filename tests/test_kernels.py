@@ -253,21 +253,28 @@ class TestPagedGather:
                     k += 1
         return table
 
+    LAYERS = 3
+
+    @pytest.mark.parametrize("l", [0, 1, LAYERS - 1])
     @pytest.mark.parametrize("b,maxp,np_pages,ps,kv,hd", [
         (1, 2, 4, 4, 1, 4),
         (3, 4, 16, 8, 2, 8),
         (2, 3, 5, 2, 4, 16),
     ])
-    def test_matches_oracle(self, b, maxp, np_pages, ps, kv, hd):
+    def test_matches_oracle(self, b, maxp, np_pages, ps, kv, hd, l):
+        # the stacked (L, NP, PS, KV, hd) pool, read at layer l: the same
+        # pages as the oracle's gather of that layer's pool alone
         from repro.kernels.paged_kv import (
             paged_gather_pallas, paged_gather_ref, paged_gather_take)
         rng = np.random.default_rng(b * 100 + maxp)
-        pool = jnp.asarray(rng.normal(size=(np_pages, ps, kv, hd)),
-                           jnp.float32)
+        pool = jnp.asarray(
+            rng.normal(size=(self.LAYERS, np_pages, ps, kv, hd)),
+            jnp.float32)
         table = jnp.asarray(self._tables(rng, b, maxp, np_pages))
-        out_k = paged_gather_pallas(pool, table, interpret=True)
-        out_t = paged_gather_take(pool, table)
-        out_r = paged_gather_ref(pool, table)
+        layer = jnp.asarray(l, jnp.int32)
+        out_k = paged_gather_pallas(pool, table, layer, interpret=True)
+        out_t = paged_gather_take(pool, table, layer)
+        out_r = paged_gather_ref(pool[l], table)
         assert out_k.shape == (b, maxp * ps, kv, hd)
         np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
         np.testing.assert_array_equal(np.asarray(out_t), np.asarray(out_r))
@@ -275,12 +282,45 @@ class TestPagedGather:
     def test_unmapped_pages_zero(self):
         from repro.kernels.paged_kv import (
             paged_gather_pallas, paged_gather_take)
-        pool = jnp.ones((4, 2, 1, 2), jnp.float32)
+        # every page of layer l holds l + 1, so a zero can only come from
+        # the unmapped-entry mask
+        pool = (jnp.arange(1, 4, dtype=jnp.float32).reshape(3, 1, 1, 1, 1)
+                * jnp.ones((3, 4, 2, 1, 2), jnp.float32))
         table = jnp.asarray([[-1, 2], [1, -1]], jnp.int32)
-        for out in (paged_gather_pallas(pool, table, interpret=True),
-                    paged_gather_take(pool, table)):
+        layer = jnp.asarray(1, jnp.int32)
+        for out in (paged_gather_pallas(pool, table, layer, interpret=True),
+                    paged_gather_take(pool, table, layer)):
             out = np.asarray(out)
             np.testing.assert_array_equal(out[0, :2], 0.0)   # unmapped
-            np.testing.assert_array_equal(out[0, 2:], 1.0)
-            np.testing.assert_array_equal(out[1, :2], 1.0)
+            np.testing.assert_array_equal(out[0, 2:], 2.0)
+            np.testing.assert_array_equal(out[1, :2], 2.0)
             np.testing.assert_array_equal(out[1, 2:], 0.0)
+
+    def test_update_decode_writes_only_its_rows(self):
+        # one decode token at layer l lands in exactly the B rows
+        # [l, table[b, cur // PS], cur % PS] of the stacked pool (the trash
+        # page 0 for an unmapped slot), bit for bit; nothing else moves
+        from repro.models.attention import PagedKVLayer, paged_update_decode
+        rng = np.random.default_rng(7)
+        n_layers, np_pages, ps, kv, hd = 4, 9, 4, 2, 8
+        table = np.asarray([[3, 5, -1], [1, 8, 2], [-1, -1, -1]], np.int32)
+        b, cur, l = table.shape[0], 6, 2
+        k = rng.normal(size=(n_layers, np_pages, ps, kv, hd)).astype(
+            np.float32)
+        v = rng.normal(size=k.shape).astype(np.float32)
+        k_new = rng.normal(size=(b, 1, kv, hd)).astype(np.float32)
+        v_new = rng.normal(size=(b, 1, kv, hd)).astype(np.float32)
+        layer = PagedKVLayer(jnp.asarray(k), jnp.asarray(v),
+                             jnp.asarray(table), jnp.asarray(cur, jnp.int32),
+                             jnp.asarray(l, jnp.int32), ps)
+        out = jax.jit(paged_update_decode)(layer, jnp.asarray(k_new),
+                                           jnp.asarray(v_new))
+        assert int(out.length) == cur + 1
+        pages = [5, 8, 0]                    # table[:, 6 // 4], -1 -> trash
+        for pool, new, old in ((out.k, k_new, k), (out.v, v_new, v)):
+            want = old.copy()
+            for i, page in enumerate(pages):
+                want[l, page, cur % ps] = new[i, 0]
+            got = np.asarray(pool)
+            assert got.tobytes() == want.tobytes()
+            assert (got != old).any(axis=(3, 4)).sum() == b

@@ -10,6 +10,7 @@ library, and pytest-xdist workers import every test file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,15 +59,62 @@ def compile_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def on_chip(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def custom_call_results(hlo: str) -> list:
+    """Result types of the ``tpu_custom_call``s in compiled HLO text."""
+    return re.findall(r"= (\w+\[[\d,]*\])\S* custom-call\(.*"
+                      r"custom_call_target=\"tpu_custom_call\"", hlo)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_paged_gather(one_chip, dtype):
-    # one layer of the olmo-1b pool a batch-4 engine holds at max_len 320
+    # the olmo-1b layer-stacked pool a batch-4 engine holds at max_len 320,
+    # read at a layer given as a run-time operand
     cfg = get_config("olmo-1b")
     ps, maxp, b = 16, 20, 4
-    pool = (1 + b * maxp, ps, cfg.num_kv_heads, cfg.head_dim)
+    pool = (cfg.num_layers, 1 + b * maxp, ps, cfg.num_kv_heads,
+            cfg.head_dim)
     hlo = compile_text(paged_gather_pallas, one_chip, (pool, dtype),
-                       ((b, maxp), jnp.int32))
-    assert "tpu_custom_call" in hlo
+                       ((b, maxp), jnp.int32), ((), jnp.int32))
+    short = jnp.dtype(dtype).name.replace("float", "f")   # f32, bf16
+    e = cfg.num_kv_heads * cfg.head_dim
+    assert custom_call_results(hlo) == [f"{short}[{b * maxp},{ps},{e}]"]
+
+
+def test_paged_serve_step_keeps_pool_in_place(one_chip, monkeypatch):
+    # the olmo-1b decode step at the chat cell's sizes (batch 16, max_len
+    # 256, pages of 16, float32 pool), cache donated as the engine does:
+    # the layer scan carries the stacked pool and writes it in place, so
+    # the step needs less scratch than one layer's K pool (a whole-pool
+    # slice, update or copy would need at least that)
+    import repro.kernels.paged_kv as paged_kv
+    from repro.models.transformer import init_paged_cache, init_params
+    from repro.serve.engine import make_serve_step
+
+    monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    cfg = get_config("olmo-1b")
+    b, max_len, ps = 16, 256, 16
+    num_pages = 1 + b * (max_len // ps)
+    params = on_chip(jax.eval_shape(lambda k: init_params(cfg, k),
+                                    jax.random.key(0)), one_chip)
+    cache = on_chip(jax.eval_shape(lambda: init_paged_cache(
+        cfg, b, max_len, page_size=ps, num_pages=num_pages,
+        dtype=jnp.float32)), one_chip)
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    start = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+    temps = jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip)
+    step = jax.jit(make_serve_step(cfg), donate_argnums=(2,))
+    compiled = step.lower(params, tokens, cache, start, temps, key).compile()
+    layer_pool = num_pages * ps * cfg.num_kv_heads * cfg.head_dim * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
+    view = f"f32[{b * max_len // ps},{ps},{cfg.num_kv_heads * cfg.head_dim}]"
+    assert custom_call_results(compiled.as_text()) == [view, view]
 
 
 @pytest.mark.parametrize("kernel", [bucket_pack_pallas, bucket_unpack_pallas])
