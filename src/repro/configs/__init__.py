@@ -1,6 +1,6 @@
 """Architecture registry.
 
-``get_config("<arch-id>")`` resolves the 10 assigned architectures (by their
+``get_config("<arch-id>")`` resolves the listed architectures (by their
 public ids, e.g. ``gemma-2b``) plus variant suffixes:
 
 * ``<id>-smoke``    — reduced same-family config for CPU smoke tests
@@ -35,6 +35,7 @@ _ARCH_MODULES = {
     "olmo-1b": "olmo_1b",
     "arctic-480b": "arctic_480b",
     "musicgen-large": "musicgen_large",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS: List[str] = list(_ARCH_MODULES)

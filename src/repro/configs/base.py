@@ -38,6 +38,7 @@ class SSMConfig:
     chunk_size: int = 256       # SSD chunk length for the blocked scan
     conv_width: int = 4         # causal depthwise conv window
     ngroups: int = 1            # B/C groups (GVA); 1 == multi-value attention
+    conv_bias: bool = False     # a bias on the depthwise conv (granite-4.0-h)
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -65,6 +66,8 @@ class ModelConfig:
     tie_embeddings: bool = False
     parallel_block: bool = False  # attention and FFN in parallel (command-r)
     rope_theta: float = 10_000.0
+    position_embedding_type: str = "rope"  # rope | nope (no positions)
+    norm_eps: Optional[float] = None       # None: 1e-6 rms, 1e-5 layernorm
     sliding_window: Optional[int] = None   # SWA window; None => full causal
 
     # --- mixtures / state-space / hybrid ------------------------------------
@@ -73,6 +76,16 @@ class ModelConfig:
     # hybrid (zamba2): one *shared-weight* attention block applied every
     # ``hybrid_attn_every`` backbone blocks [arXiv:2411.15242].
     hybrid_attn_every: int = 0
+    # per-layer mixer pattern (granite-4.0-h): "mamba" or "attention" for
+    # each layer, every layer with its own weights and an MLP. Empty: the
+    # family's own (attention everywhere; mamba everywhere for ssm).
+    layer_types: Tuple[str, ...] = ()
+
+    # --- muP multipliers (granite) -------------------------------------------
+    attention_multiplier: Optional[float] = None  # softmax scale; None: hd^-0.5
+    embedding_multiplier: float = 1.0  # scales the token embeddings
+    residual_multiplier: float = 1.0   # scales both branches of every layer
+    logits_scaling: float = 1.0        # divides the logits
 
     # --- modality frontends (stubbed per the assignment carve-out) ----------
     modality: str = "text"       # text | vlm | audio
@@ -108,16 +121,41 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def __post_init__(self):
+        # a list (a JSON file's) becomes a tuple, so the frozen config hashes
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            assert len(self.layer_types) == self.num_layers
+            assert set(self.layer_types) <= {"mamba", "attention"}
         if self.family in ("ssm",):
             assert self.num_heads == 0 and self.ssm is not None
         if self.family in ("moe",):
             assert self.moe is not None
         if self.family == "hybrid":
-            assert self.ssm is not None and self.hybrid_attn_every > 0
+            assert self.ssm is not None
+            assert (self.hybrid_attn_every > 0) != bool(self.layer_types)
         if self.num_heads:
             assert self.head_dim * self.num_heads >= self.d_model // 2
 
     # --- derived sizes -------------------------------------------------------
+    @property
+    def mixers(self) -> Optional[Tuple[str, ...]]:
+        """Each layer's token mixer, ``"attention"`` or ``"mamba"``; None
+        where the layers are not one mixer each (zamba's shared block)."""
+        if self.layer_types:
+            return self.layer_types
+        if self.family == "hybrid":
+            return None
+        kind = "mamba" if self.family == "ssm" else "attention"
+        return (kind,) * self.num_layers
+
+    @property
+    def period(self) -> int:
+        """Length of the shortest run of ``mixers`` that repeats to make
+        the whole stack (1 where every layer is alike)."""
+        m = self.mixers
+        return next(p for p in range(1, len(m) + 1)
+                    if len(m) % p == 0 and m == m[:p] * (len(m) // p))
+
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
@@ -144,7 +182,7 @@ class ModelConfig:
         # in_proj emits [z, x, B, C, dt]; out_proj returns to d_model.
         d_bc = 2 * c.ngroups * c.d_state
         in_proj = self.d_model * (2 * d_in + d_bc + nheads)
-        conv = (d_in + d_bc) * c.conv_width
+        conv = (d_in + d_bc) * (c.conv_width + c.conv_bias)
         return in_proj + conv + nheads * 2 + d_in * self.d_model  # + A, D + out
 
     def layer_params(self) -> int:
@@ -165,7 +203,12 @@ class ModelConfig:
         """Approximate total params (embeddings + layers + head)."""
         embed = self.vocab_size * self.d_model * self.num_codebooks
         head = 0 if self.tie_embeddings else self.vocab_size * self.d_model * self.num_codebooks
-        if self.family == "hybrid":
+        if self.layer_types:
+            n_attn = self.layer_types.count("attention")
+            body = (n_attn * self.attn_params()
+                    + (self.num_layers - n_attn) * self.ssm_params()
+                    + self.num_layers * self.ffn_params_dense())
+        elif self.family == "hybrid":
             body = self.num_layers * self.ssm_params()
             # ONE shared attention block (+ its FFN), reused at each interleave
             shared = self.attn_params() + self.ffn_params_dense()
@@ -213,6 +256,9 @@ class ModelConfig:
             changes["ssm"] = replace(self.ssm, d_state=16, head_dim=32, chunk_size=32)
         if self.hybrid_attn_every:
             changes["hybrid_attn_every"] = 2
+        if self.layer_types:  # one whole period of the pattern
+            changes["num_layers"] = self.period
+            changes["layer_types"] = self.layer_types[: self.period]
         return replace(self, **changes)
 
     def with_opts(self, *opts: str) -> "ModelConfig":

@@ -27,6 +27,7 @@ this entry point.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +45,8 @@ def _kernel(layer_ref, table_ref, pool_ref, out_ref):
 
 def paged_gather_pallas(pool: jax.Array, table: jax.Array, layer, *,
                         interpret: bool = False) -> jax.Array:
-    """pool: (L, NP, PS, KV, hd) the layer-stacked page pool; table:
+    """pool: (L, NP, PS, KV, hd) the layer-stacked page pool (or (L, NP,
+    PS, KV*hd), the layout of heads narrower than a lane tile); table:
     (B, MAXP) int32 pool page ids (-1 unmapped); layer: () int32 which
     layer's pages to read. Returns (B, MAXP*PS, KV, hd) — slot b's pages
     of that layer in logical order, unmapped pages zero-filled.
@@ -59,7 +61,7 @@ def paged_gather_pallas(pool: jax.Array, table: jax.Array, layer, *,
     b, maxp = table.shape
     ps = pool.shape[2]
     tail = pool.shape[3:]
-    e = tail[0] * tail[1]
+    e = math.prod(tail)
     flat_table = table.reshape(-1)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -69,7 +71,7 @@ def paged_gather_pallas(pool: jax.Array, table: jax.Array, layer, *,
             pl.BlockSpec((None, None, ps) + tail,
                          lambda t, layer_ref, table_ref: (
                              layer_ref[0], jnp.maximum(table_ref[t], 0),
-                             0, 0, 0)),
+                             0) + (0,) * len(tail)),
         ],
         out_specs=pl.BlockSpec((None, ps, e),
                                lambda t, layer_ref, table_ref: (t, 0, 0)),
@@ -92,7 +94,7 @@ def paged_gather_take(pool: jax.Array, table: jax.Array, layer
     ps = pool.shape[2]
     ids = jnp.clip(table, 0, pool.shape[1] - 1)
     pages = pool[layer, ids]                          # (B, MAXP, PS, KV, hd)
-    mapped = (table >= 0).reshape(b, maxp, 1, 1, 1)
+    mapped = (table >= 0).reshape((b, maxp) + (1,) * (pages.ndim - 2))
     pages = jnp.where(mapped, pages, jnp.zeros((), pool.dtype))
     return pages.reshape((b, maxp * ps) + pool.shape[3:])
 

@@ -55,8 +55,8 @@ def ssd_chunk_ref(x, dt, cum, B, C) -> Tuple[jax.Array, jax.Array]:
     c = x.shape[0]
     f32 = jnp.float32
     x, dt, cum, B, C = (t.astype(f32) for t in (x, dt, cum, B, C))
-    L = jnp.exp(cum[:, None] - cum[None, :])
-    L = jnp.where(jnp.tril(jnp.ones((c, c), bool)), L, 0.0)
+    L = jnp.exp(jnp.where(jnp.tril(jnp.ones((c, c), bool)),
+                          cum[:, None] - cum[None, :], -jnp.inf))
     W = (C @ B.T) * L * dt[None, :]
     y = W @ x
     decay_end = jnp.exp(cum[-1] - cum)

@@ -31,11 +31,12 @@ def _kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref, *, chunk: int):
     B = b_ref[0, 0].astype(f32)          # (c, n)
     C = c_ref[0, 0].astype(f32)          # (c, n)
 
-    # decay L[i,j] = exp(cum_i - cum_j), lower-triangular
+    # decay L[i,j] = exp(cum_i - cum_j), lower-triangular; masked before
+    # the exp, which overflows above the diagonal
     diff = cum - cum.reshape(1, chunk)                       # (c, c)
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    L = jnp.exp(jnp.where(jj <= ii, diff, -jnp.inf))
 
     CB = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=f32)     # (c, c)

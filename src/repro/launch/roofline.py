@@ -299,10 +299,9 @@ def _attn_flops_fwd(cfg: ModelConfig, batch: int, seq: int,
             eff * (1 - eff / (2 * max(seq, 1))))  # causal and/or banded
     else:
         eff_avg = eff
-    n_layers = (cfg.num_layers if cfg.family != "hybrid"
-                else cfg.num_layers // cfg.hybrid_attn_every)
     # QK^T + PV
-    return 4.0 * batch * seq * eff_avg * cfg.num_heads * cfg.head_dim * n_layers
+    return (4.0 * batch * seq * eff_avg * cfg.num_heads * cfg.head_dim
+            * _attn_layers(cfg))
 
 
 def _ssd_flops_fwd(cfg: ModelConfig, batch: int, seq: int) -> float:
@@ -387,9 +386,9 @@ def analytic_hbm_bytes(cfg: ModelConfig, shape: InputShape) -> float:
 def _attn_layers(cfg: ModelConfig) -> int:
     if cfg.num_heads == 0:
         return 0
-    if cfg.family == "hybrid":
+    if cfg.mixers is None:  # zamba2's shared-attention sites
         return cfg.num_layers // cfg.hybrid_attn_every
-    return cfg.num_layers
+    return cfg.mixers.count("attention")
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,13 @@ def _repeat_kv(k, n_rep: int):
                             ).reshape(b, s, kv * n_rep, hd)
 
 
+def softmax_scale(cfg: ModelConfig, head_dim: int) -> float:
+    """The scale of attention scores: ``attention_multiplier`` where the
+    config gives one (granite's muP), else ``head_dim ** -0.5``."""
+    m = cfg.attention_multiplier
+    return 1.0 / math.sqrt(head_dim) if m is None else m
+
+
 def causal_mask(q_len: int, kv_len: int, *, window: Optional[int] = None,
                 q_offset: int = 0) -> jax.Array:
     """(q_len, kv_len) bool mask. ``window`` adds the sliding-window band."""
@@ -59,7 +66,7 @@ def attention(cfg: ModelConfig, q, k, v, *, q_offset: int = 0,
     b, sq, h, hd = q.shape
     n_rep = h // k.shape[2]
     k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
-    scale = 1.0 / math.sqrt(hd)
+    scale = softmax_scale(cfg, hd)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if mask is None:
         mask = causal_mask(sq, k.shape[1], window=cfg.sliding_window,
@@ -121,6 +128,17 @@ def _expand_to_cache(cache: KVCache, k_new):
     return _expand_heads(k_new, cache.k.shape[2])
 
 
+def _pool_heads(pool, hd: int) -> int:
+    """KV heads a page pool stores: ``(L, NP, PS, KV, hd)``, or ``(L, NP,
+    PS, KV*hd)`` where a head is narrower than a lane tile."""
+    return math.prod(pool.shape[3:]) // hd
+
+
+def _pool_rows(pool, rows):
+    """K or V ``(..., KV, hd)`` in the pool's layout of a token's heads."""
+    return rows.reshape(rows.shape[:-2] + pool.shape[3:])
+
+
 def cache_update_decode(cache: KVCache, k_new, v_new) -> KVCache:
     """Append ONE token (k_new/v_new: (B,1,KV,hd))."""
     k_new = _expand_to_cache(cache, k_new)
@@ -158,7 +176,7 @@ def decode_attention(cfg: ModelConfig, q, cache: KVCache,
     # the compute dtype at read.
     k = _repeat_kv(cache.k, n_rep).astype(q.dtype)
     v = _repeat_kv(cache.v, n_rep).astype(q.dtype)
-    scale = 1.0 / math.sqrt(hd)
+    scale = softmax_scale(cfg, hd)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     # validity: slot i holds absolute position p(i); valid iff p(i) <= cur.
     idx = jnp.arange(s_cache)
@@ -257,8 +275,9 @@ def paged_update_decode(layer: PagedKVLayer, k_new, v_new) -> PagedKVLayer:
     invariant, so the scatter never collides (except in the trash page,
     whose content is never read)."""
     ps = layer.page_size
-    k_new = _expand_heads(k_new, layer.k.shape[3])
-    v_new = _expand_heads(v_new, layer.k.shape[3])
+    heads = _pool_heads(layer.k, k_new.shape[-1])
+    k_new = _pool_rows(layer.k, _expand_heads(k_new, heads))
+    v_new = _pool_rows(layer.k, _expand_heads(v_new, heads))
     pos = layer.length
     ids = _paged_write_ids(layer.table, pos[None], ps)[:, 0]  # (B,)
     off = pos % ps
@@ -274,16 +293,17 @@ def paged_prefill_update(layer: PagedKVLayer, k_new, v_new) -> PagedKVLayer:
     stacked pool; positions whose pages are unmapped (each slot's left-pad
     prefix) go to the trash page."""
     ps = layer.page_size
-    k_new = _expand_heads(k_new, layer.k.shape[3])
-    v_new = _expand_heads(v_new, layer.k.shape[3])
+    heads = _pool_heads(layer.k, k_new.shape[-1])
+    k_new = _expand_heads(k_new, heads)
+    v_new = _expand_heads(v_new, heads)
     b, s = k_new.shape[:2]
     npg = -(-s // ps)
     pad = npg * ps - s
     if pad:
         k_new = jnp.pad(k_new, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v_new = jnp.pad(v_new, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kp = k_new.reshape((b, npg, ps) + k_new.shape[2:])
-    vp = v_new.reshape((b, npg, ps) + v_new.shape[2:])
+    kp = k_new.reshape((b, npg, ps) + layer.k.shape[3:])
+    vp = v_new.reshape((b, npg, ps) + layer.v.shape[3:])
     ids = layer.table[:, :npg]
     ids = jnp.where(ids >= 0, ids, 0)                 # (B, npg)
     l = layer.layer
@@ -300,15 +320,18 @@ def paged_splice(cache: PagedKVCache, slot, dest, k_rows, v_rows
     page-table-indirect and not page-aligned (positions below the admitted
     request's ``start`` fall through unmapped entries to the trash page)."""
     ps = cache.page_size
-    ll, np_, _, kv, hd = cache.k.shape
+    ll, np_ = cache.k.shape[:2]
+    tail = cache.k.shape[3:]
     s = k_rows.shape[1]
     pos = jnp.asarray(dest, jnp.int32) + jnp.arange(s, dtype=jnp.int32)
     row = jnp.take(cache.table, jnp.asarray(slot, jnp.int32), axis=0)
     ids = jnp.take(row, pos // ps)
     ids = jnp.where(ids >= 0, ids, 0)
     flat = ids * ps + pos % ps                        # (S,)
-    k = cache.k.reshape(ll, np_ * ps, kv, hd)
-    v = cache.v.reshape(ll, np_ * ps, kv, hd)
+    k = cache.k.reshape((ll, np_ * ps) + tail)
+    v = cache.v.reshape((ll, np_ * ps) + tail)
+    k_rows = k_rows.reshape(k_rows.shape[:2] + tail)
+    v_rows = v_rows.reshape(v_rows.shape[:2] + tail)
     k = k.at[:, flat].set(k_rows.astype(k.dtype)).reshape(cache.k.shape)
     v = v.at[:, flat].set(v_rows.astype(v.dtype)).reshape(cache.v.shape)
     return PagedKVCache(k, v, cache.table, cache.length, ps)
@@ -323,8 +346,11 @@ def paged_decode_attention(cfg: ModelConfig, q, layer: PagedKVLayer,
     ``[start, length)`` — which is what makes paged-vs-contiguous token
     equality exact rather than approximate."""
     from repro.kernels.paged_kv import paged_gather
+    heads = (-1, q.shape[-1])  # a merged row of heads parts again
     k_view = paged_gather(layer.k, layer.table, layer.layer)
     v_view = paged_gather(layer.v, layer.table, layer.layer)
+    k_view = k_view.reshape(k_view.shape[:2] + heads)
+    v_view = v_view.reshape(v_view.shape[:2] + heads)
     view = KVCache(k_view, v_view, layer.length, ring=False)
     return decode_attention(cfg, q, view, start=start)
 

@@ -39,11 +39,12 @@ def layer_norm(x, scale=None, bias=None, eps: float = 1e-5):
 
 def apply_norm(cfg: ModelConfig, x, params: Optional[dict]):
     """Dispatch on cfg.norm. ``nonparametric`` (OLMo) takes no params."""
+    eps = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
     if cfg.norm == "nonparametric":
-        return layer_norm(x, None, None)
+        return layer_norm(x, None, None, **eps)
     if cfg.norm == "layernorm":
-        return layer_norm(x, params["scale"], params.get("bias"))
-    return rms_norm(x, params["scale"])
+        return layer_norm(x, params["scale"], params.get("bias"), **eps)
+    return rms_norm(x, params["scale"], **eps)
 
 
 # ---------------------------------------------------------------------------

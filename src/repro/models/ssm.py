@@ -64,10 +64,12 @@ def ssd_chunked(x, dt, A, B, C, *, chunk: int,
     cum_end = cum[:, :, -1:, :]                              # (b,nc,1,h)
 
     # ---- intra-chunk (quadratic/dual form) --------------------------------
-    # L[i,j] = exp(cum_i - cum_j) for j <= i, else 0
-    Li = jnp.exp(cum[:, :, :, None, :] - cum[:, :, None, :, :])   # (b,nc,i,j,h)
+    # L[i,j] = exp(cum_i - cum_j) for j <= i, else 0. Masked before the exp:
+    # above the diagonal cum_i - cum_j > 0 grows with the chunk, and its exp
+    # overflows to inf, whose masked-out gradient is NaN.
+    diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # (b,nc,i,j,h)
     mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    Li = jnp.where(mask[None, None, :, :, None], Li, 0.0)
+    Li = jnp.exp(jnp.where(mask[None, None, :, :, None], diff, -jnp.inf))
     CB = jnp.einsum("bnigq,bnjgq->bnijg", Cs, Bs)            # (b,nc,i,j,g)
     CB = jnp.repeat(CB, rep, axis=4)                         # -> heads
     W = CB * Li * dts[:, :, None, :, :]                      # weight on x_j
@@ -160,21 +162,36 @@ def _split_proj(cfg: ModelConfig, zxbcdt):
     return z, xbc, dt
 
 
-def _causal_conv(xbc, w):
-    """Depthwise causal conv. xbc: (b,s,ch); w: (width, ch)."""
+def _causal_conv(xbc, w, bias=None):
+    """Depthwise causal conv. xbc: (b,s,ch); w: (width, ch); bias: (ch,)."""
     width = w.shape[0]
     pad = jnp.zeros_like(xbc[:, : width - 1])
     xp = jnp.concatenate([pad, xbc], axis=1)
     out = jnp.zeros_like(xbc, dtype=jnp.float32)
     for i in range(width):
         out = out + xp[:, i: i + xbc.shape[1]].astype(jnp.float32) * w[i].astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return out.astype(xbc.dtype)
 
 
+def _gate_norm(cfg: ModelConfig, y, z, scale):
+    """The gated RMSNorm ``norm(y * silu(z))``."""
+    eps = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    return rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                    scale, **eps)
+
+
 def mamba2_forward(cfg: ModelConfig, x, p, shard=None,
-                   initial: Optional[SSMState] = None
+                   start: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, SSMState]:
-    """Full-sequence Mamba2 block. x: (b,s,d) -> (y: (b,s,d), final state)."""
+    """Full-sequence Mamba2 block from a zero state. x: (b,s,d) -> (y:
+    (b,s,d), final state).
+
+    ``start`` — (B,) int32 left-pad lengths: positions below ``start[b]``
+    take no step (``dt = 0``, so the state is left as it was) and feed the
+    conv a zero input (what an unpadded row's causal conv sees there), so a
+    padded row's outputs and final state are an unpadded row's."""
     c = cfg.ssm
     b, s, _ = x.shape
     d_in = c.d_inner(cfg.d_model)
@@ -182,31 +199,32 @@ def mamba2_forward(cfg: ModelConfig, x, p, shard=None,
 
     zxbcdt = x @ p["in_proj"].astype(x.dtype)
     z, xbc, dt = _split_proj(cfg, zxbcdt)
-    xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"]))
+    if start is not None:
+        real = (jnp.arange(s)[None, :] >= start[:, None])[..., None]
+        xbc = jnp.where(real, xbc, 0)
+    conv_in = xbc
+    xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"], p.get("conv_b")))
     xv, B, C = jnp.split(xbc, [d_in, d_in + c.ngroups * c.d_state], axis=-1)
     xv = xv.reshape(b, s, h, c.head_dim)
     B = B.reshape(b, s, c.ngroups, c.d_state)
     C = C.reshape(b, s, c.ngroups, c.d_state)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    if start is not None:
+        dt = jnp.where(real, dt, 0.0)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
     if shard is not None:
         xv = shard.heads(xv)
 
-    init_ssd = initial.ssd if initial is not None else None
-    y, final = ssd_chunked(xv, dt, A, B, C, chunk=c.chunk_size,
-                           initial_state=init_ssd)
+    y, final = ssd_chunked(xv, dt, A, B, C, chunk=c.chunk_size)
     y = y + xv * p["D"].astype(jnp.float32)[None, None, :, None].astype(xv.dtype)
-    y = y.reshape(b, s, d_in)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["gate_norm"])
+    y = _gate_norm(cfg, y.reshape(b, s, d_in), z, p["gate_norm"])
     out = y @ p["out_proj"].astype(y.dtype)
 
-    # conv tail state for decode continuation
+    # conv tail state for decode continuation: the last raw conv inputs
     pad_needed = c.conv_width - 1
-    raw_xbc = _split_proj(cfg, zxbcdt)[1]
-    conv_state = raw_xbc[:, -pad_needed:] if s >= pad_needed else jnp.pad(
-        raw_xbc, ((0, 0), (pad_needed - s, 0), (0, 0)))
+    conv_state = conv_in[:, -pad_needed:] if s >= pad_needed else jnp.pad(
+        conv_in, ((0, 0), (pad_needed - s, 0), (0, 0)))
     return out, SSMState(conv_state, final)
 
 
@@ -223,8 +241,10 @@ def mamba2_decode(cfg: ModelConfig, x, p, state: SSMState,
     # conv over [state ; new]
     window = jnp.concatenate([state.conv, xbc[:, None]], axis=1)  # (b,w,ch)
     w = p["conv_w"].astype(jnp.float32)
-    xbc = jax.nn.silu(jnp.einsum("bwc,wc->bc", window.astype(jnp.float32), w)
-                      ).astype(x.dtype)
+    xbc = jnp.einsum("bwc,wc->bc", window.astype(jnp.float32), w)
+    if "conv_b" in p:
+        xbc = xbc + p["conv_b"].astype(jnp.float32)
+    xbc = jax.nn.silu(xbc).astype(x.dtype)
     new_conv = window[:, 1:].astype(state.conv.dtype)
 
     xv, B, C = jnp.split(xbc, [d_in, d_in + c.ngroups * c.d_state], axis=-1)
@@ -236,8 +256,6 @@ def mamba2_decode(cfg: ModelConfig, x, p, state: SSMState,
 
     y, new_ssd = ssd_decode_step(state.ssd, xv, dt, A, B, C)
     y = y + xv * p["D"].astype(jnp.float32)[None, :, None].astype(xv.dtype)
-    y = y.reshape(b, d_in)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["gate_norm"])
+    y = _gate_norm(cfg, y.reshape(b, d_in), z, p["gate_norm"])
     out = (y @ p["out_proj"].astype(y.dtype))[:, None]
     return out, SSMState(new_conv, new_ssd)

@@ -1,21 +1,25 @@
 """The unified model over all assigned architecture families.
 
 One ``Model`` class covers: dense GQA/MQA transformers (gemma/yi/command-r/
-olmo), MoE (mixtral/arctic), SSM (mamba2), hybrid SSM+shared-attention
-(zamba2), VLM (phi-3-vision: stubbed patch embeddings spliced before text)
-and audio (musicgen: 4 EnCodec codebook streams, summed embeddings, one LM
-head per codebook).
+olmo), MoE (mixtral/arctic), SSM (mamba2), hybrids with a per-layer mixer
+pattern (granite-4.0-h: Mamba-2 and attention layers, an MLP in each), the
+hybrid with a shared attention block (zamba2), VLM (phi-3-vision: stubbed
+patch embeddings spliced before text) and audio (musicgen: 4 EnCodec
+codebook streams, summed embeddings, one LM head per codebook).
 
-Layers are stacked along a leading L axis and executed with ``lax.scan``
-(compile-time control for 512-device dry-runs); hybrid archs scan groups of
-``hybrid_attn_every`` SSM blocks followed by ONE shared-weight attention
-block (zamba2's parameter-sharing trick — the weights are shared, but each
-application site keeps its own KV cache).
+Layers are stacked along a leading axis, one stack per kind of weight, and
+executed with ``lax.scan`` over periods of the mixer pattern (one layer for
+all but the pattern hybrids; compile-time control for 512-device
+dry-runs). zamba2 scans groups of ``hybrid_attn_every`` SSM blocks followed
+by ONE shared-weight attention block (its parameter-sharing trick — the
+weights are shared, but each application site keeps its own KV cache).
 """
 
 from __future__ import annotations
 
+import functools
 import math
+import operator
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -119,7 +123,7 @@ def _ssm_params(cfg: ModelConfig, key, dims: Tuple[int, ...], dtype):
     dt_bias = dt + jnp.log(-jnp.expm1(-dt))
     a_init = jnp.broadcast_to(
         jnp.log(jnp.linspace(1.0, 16.0, nh)), dims + (nh,))
-    return {
+    p = {
         "in_proj": dense_init(ks[0], dims + (d, proj_out), dtype=dtype),
         "conv_w": 0.1 * jax.random.normal(ks[1], dims + (c.conv_width, d_in + d_bc),
                                           jnp.float32).astype(dtype),
@@ -129,6 +133,9 @@ def _ssm_params(cfg: ModelConfig, key, dims: Tuple[int, ...], dtype):
         "gate_norm": jnp.ones(dims + (d_in,), jnp.float32),
         "out_proj": dense_init(ks[0], dims + (d_in, d), dtype=dtype),
     }
+    if c.conv_bias:
+        p["conv_b"] = jnp.zeros(dims + (d_in + d_bc,), dtype)
+    return p
 
 
 def _moe_params(cfg: ModelConfig, key, dims: Tuple[int, ...], dtype):
@@ -162,25 +169,31 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
         params["img_proj"] = {"w": dense_init(
             keys[1], (IMG_EMBED_DIM, cfg.d_model), dtype=dtype)}
 
+    # one stack per kind of weight: "attn" over the attention layers, "ssm"
+    # over the Mamba-2 layers, norms and MLPs over every layer
     dims = (L,)
-    if cfg.family in ("ssm", "hybrid"):
-        layer = {"ssm": _ssm_params(cfg, keys[2], dims, dtype),
-                 "norm1": _norm_params(cfg, dims)}
-        if cfg.family == "hybrid":
-            params["shared_attn"] = {
-                "attn": _attn_params(cfg, keys[3], (), dtype),
-                "ffn": _ffn_params(cfg, keys[4], (), dtype),
-                "norm1": _norm_params(cfg, ()),
-                "norm2": _norm_params(cfg, ()),
-            }
+    layer = {"norm1": _norm_params(cfg, dims)}
+    mixers = cfg.mixers
+    if mixers is None:  # zamba2: Mamba-2 blocks and ONE shared block
+        layer["ssm"] = _ssm_params(cfg, keys[2], dims, dtype)
+        params["shared_attn"] = {
+            "attn": _attn_params(cfg, keys[3], (), dtype),
+            "ffn": _ffn_params(cfg, keys[4], (), dtype),
+            "norm1": _norm_params(cfg, ()),
+            "norm2": _norm_params(cfg, ()),
+        }
     else:
-        layer = {"attn": _attn_params(cfg, keys[2], dims, dtype),
-                 "norm1": _norm_params(cfg, dims)}
+        n_attn = mixers.count("attention")
+        if n_attn:
+            layer["attn"] = _attn_params(cfg, keys[2], (n_attn,), dtype)
+        if n_attn < L:
+            layer["ssm"] = _ssm_params(cfg, keys[6] if n_attn else keys[2],
+                                       (L - n_attn,), dtype)
         if cfg.moe is not None:
             layer["moe"] = _moe_params(cfg, keys[3], dims, dtype)
-        else:
+        elif cfg.d_ff:
             layer["ffn"] = _ffn_params(cfg, keys[3], dims, dtype)
-        if not cfg.parallel_block:
+        if cfg.d_ff and not cfg.parallel_block:
             layer["norm2"] = _norm_params(cfg, dims)
     params["layers"] = {k: v for k, v in layer.items() if v is not None}
 
@@ -203,11 +216,37 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 class DecodeCache(NamedTuple):
-    """Per-arch decode state, layer-stacked along the leading axis."""
+    """Per-arch decode state, layer-stacked along the leading axis: K/V of
+    the attention layers (or sites) and the recurrent state of the Mamba-2
+    layers, each with a row per batch slot."""
 
-    kv: Optional[KVCache]       # (L|n_sites, B, S, KV, hd) stacked
-    ssm: Optional[SSMState]     # (L, ...) stacked
+    kv: Optional[KVCache]       # (n_attn, B, S, KV, hd) stacked, or paged
+    ssm: Optional[SSMState]     # (n_mamba, B, ...) stacked
     length: jax.Array           # () int32 — absolute tokens decoded
+
+
+def _layer_counts(cfg: ModelConfig) -> Tuple[int, int]:
+    """(layers or sites with a K/V cache, layers with Mamba-2 state)."""
+    mixers = cfg.mixers
+    if mixers is None:  # zamba2: every block Mamba-2, shared-attention sites
+        return cfg.num_layers // cfg.hybrid_attn_every, cfg.num_layers
+    n_attn = mixers.count("attention")
+    return n_attn, cfg.num_layers - n_attn
+
+
+def _stack(tree, n):
+    return jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
+
+
+def _ssm_cache(cfg: ModelConfig, batch: int, dtype) -> Optional[SSMState]:
+    """Zeroed Mamba-2 state for every Mamba-2 layer: the SSD state in
+    float32, the conv window in the cache dtype."""
+    n = _layer_counts(cfg)[1]
+    if not n:
+        return None
+    st = _stack(SSMState.init(cfg, batch, dtype=jnp.float32), n)
+    return SSMState(st.conv.astype(dtype), st.ssd)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -216,44 +255,36 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         # OPT(kv_fp8): fp8 KV storage — halves the decode memory-roofline
         # term (EXPERIMENTS §Perf); dequantized at attention read.
         dtype = jnp.float8_e4m3fn
-    def stack(tree, n):
-        return jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
-
-    kv = ssm = None
-    if cfg.family in ("ssm", "hybrid"):
-        ssm = stack(SSMState.init(cfg, batch, dtype=jnp.float32), cfg.num_layers)
-        ssm = SSMState(ssm.conv.astype(dtype), ssm.ssd)
-        if cfg.family == "hybrid":
-            n_sites = cfg.num_layers // cfg.hybrid_attn_every
-            kv0 = KVCache.init(cfg, batch, max_len, dtype)
-            kv = KVCache(
-                jnp.broadcast_to(kv0.k[None], (n_sites,) + kv0.k.shape),
-                jnp.broadcast_to(kv0.v[None], (n_sites,) + kv0.v.shape),
-                kv0.length, kv0.ring)
-    else:
+    n_attn = _layer_counts(cfg)[0]
+    kv = None
+    if n_attn:
         kv0 = KVCache.init(cfg, batch, max_len, dtype)
-        kv = KVCache(
-            jnp.broadcast_to(kv0.k[None], (cfg.num_layers,) + kv0.k.shape),
-            jnp.broadcast_to(kv0.v[None], (cfg.num_layers,) + kv0.v.shape),
-            kv0.length, kv0.ring)
-    return DecodeCache(kv, ssm, jnp.zeros((), jnp.int32))
+        kv = KVCache(_stack(kv0.k, n_attn), _stack(kv0.v, n_attn),
+                     kv0.length, kv0.ring)
+    return DecodeCache(kv, _ssm_cache(cfg, batch, dtype),
+                       jnp.zeros((), jnp.int32))
 
 
 def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
                      page_size: int, num_pages: int,
                      dtype=jnp.bfloat16) -> DecodeCache:
     """Paged decode cache: a fixed pool of ``num_pages`` pages of
-    ``page_size`` tokens (page 0 reserved as trash) + an all-unmapped
-    per-slot page table covering virtual positions ``[0, max_len)``.
+    ``page_size`` tokens (page 0 reserved as trash) for each attention
+    layer + an all-unmapped per-slot page table covering virtual positions
+    ``[0, max_len)``, and beside it a per-slot recurrent state for each
+    Mamba-2 layer (the state has no positions, so no pages). A page holds
+    ``(page_size, KV, hd)``, or ``(page_size, KV*hd)`` for heads narrower
+    than 128.
 
-    Attention-cache architectures only: ring (sliding-window) caches reuse
-    slots modulo the window and SSM state has no per-position pages — the
-    serve engine keeps the grouped contiguous fallback for those.
+    Text models of one mixer per layer only (dense, MoE, SSM and the
+    per-layer pattern hybrids): ring (sliding-window) caches reuse slots
+    modulo the window, and zamba2's shared attention block has no per-layer
+    stack — the serve engine keeps the grouped contiguous fallback for
+    those.
     """
-    if cfg.family not in ("dense", "moe") or cfg.modality != "text":
+    if cfg.mixers is None or cfg.modality != "text":
         raise NotImplementedError(
-            f"paged KV cache needs a text attention arch, got "
+            f"paged cache needs a text model of one mixer per layer, got "
             f"family={cfg.family!r} modality={cfg.modality!r}")
     if cfg.sliding_window is not None and cfg.sliding_window < max_len:
         raise NotImplementedError(
@@ -267,11 +298,17 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
         dtype = jnp.float8_e4m3fn  # OPT(kv_fp8): see init_cache
     kvh = cfg.num_kv_heads * max(1, cfg.decode_kv_expand)
     max_pages = -(-max_len // page_size)
-    shape = (cfg.num_layers, num_pages, page_size, kvh, cfg.head_dim)
+    # a token's heads: (KV, hd), or one row of KV*hd where a head is
+    # narrower than a lane tile (128), whose (KV, hd) pages the TPU would
+    # lay out otherwise than the kernels read them
+    heads = ((kvh, cfg.head_dim) if cfg.head_dim % 128 == 0
+             else (kvh * cfg.head_dim,))
+    shape = (_layer_counts(cfg)[0], num_pages, page_size) + heads
     kv = PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                       jnp.full((batch, max_pages), -1, jnp.int32),
                       jnp.zeros((), jnp.int32), page_size)
-    return DecodeCache(kv, None, jnp.zeros((), jnp.int32))
+    return DecodeCache(kv, _ssm_cache(cfg, batch, dtype),
+                       jnp.zeros((), jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -321,8 +358,9 @@ def _attn_apply(cfg: ModelConfig, x, p, positions, shard,
     q = q.reshape(b, s, -1, cfg.head_dim)
     k = k.reshape(b, s, -1, cfg.head_dim)
     v = v.reshape(b, s, -1, cfg.head_dim)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding_type == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     if shard is not None:
         q = shard.heads(q)
 
@@ -373,52 +411,53 @@ def _prefill_cache(kv: KVCache, k, v) -> KVCache:
     return KVCache(kc, vc, kv.length + s, kv.ring)
 
 
-def _dense_block(cfg: ModelConfig, x, p, positions, shard,
-                 kv=None, decode=False, comm=None, start=None):
-    """Standard (or parallel) transformer block. Returns (x, new_kv, aux)."""
+def _scaled(cfg: ModelConfig, y):
+    """A residual branch times ``residual_multiplier`` (granite's muP)."""
+    m = cfg.residual_multiplier
+    return y if m == 1.0 else y * m
+
+
+def _block(cfg: ModelConfig, x, p, positions, shard, *, mixer="attention",
+           kv=None, state=None, decode=False, comm=None, start=None):
+    """One layer: the pre-norm token mixer (attention, or Mamba-2 over the
+    recurrent ``state``), then the pre-norm MLP (dense or MoE) where the
+    layer has one, or both side by side for a parallel block. Returns (x,
+    new_kv, new_state, aux)."""
     aux = {}
     if shard is not None:
         p = shard.materialize(p)  # OPT(fsdp): ZeRO weight gather
     inference = decode or kv is not None
     h = apply_norm(cfg, x, p.get("norm1"))
     h = maybe_bf16_grads(cfg, h)  # OPT(bf16_grads): bwd AR in 2-byte payloads
-    attn_out, new_kv = _attn_apply(cfg, h, p["attn"], positions, shard,
-                                   kv=kv, decode=decode, comm=comm,
-                                   start=start)
+    new_kv = new_state = None
+    if mixer == "attention":
+        mix, new_kv = _attn_apply(cfg, h, p["attn"], positions, shard,
+                                  kv=kv, decode=decode, comm=comm,
+                                  start=start)
+    elif decode:
+        mix, new_state = mamba2_decode(cfg, h, p["ssm"], state, shard)
+    else:
+        mix, new_state = mamba2_forward(cfg, h, p["ssm"], shard, start=start)
+
+    def mlp(h):
+        if cfg.moe is not None:
+            return moe_ffn(cfg, h, p["moe"], shard, inference=inference,
+                           comm=comm)
+        return gated_ffn(cfg, h, p["ffn"], shard, comm=comm), {}
+
     if cfg.parallel_block:
-        if cfg.moe is not None:
-            ffn_out, aux = moe_ffn(cfg, h, p["moe"], shard,
-                                   inference=inference, comm=comm)
-        else:
-            ffn_out = gated_ffn(cfg, h, p["ffn"], shard, comm=comm)
-        x = x + attn_out + ffn_out
+        ffn_out, aux = mlp(h)
+        x = x + mix + ffn_out
     else:
-        x = x + attn_out
-        h2 = apply_norm(cfg, x, p.get("norm2"))
-        h2 = maybe_bf16_grads(cfg, h2)
-        if cfg.moe is not None:
-            ffn_out, aux = moe_ffn(cfg, h2, p["moe"], shard,
-                                   inference=inference, comm=comm)
-        else:
-            ffn_out = gated_ffn(cfg, h2, p["ffn"], shard, comm=comm)
-        x = x + ffn_out
+        x = x + _scaled(cfg, mix)
+        if "ffn" in p or "moe" in p:
+            h2 = apply_norm(cfg, x, p.get("norm2"))
+            h2 = maybe_bf16_grads(cfg, h2)
+            ffn_out, aux = mlp(h2)
+            x = x + _scaled(cfg, ffn_out)
     if shard is not None:
         x = shard.hidden(x)
-    return x, new_kv, aux
-
-
-def _ssm_block(cfg: ModelConfig, x, p, shard, state=None, decode=False):
-    if shard is not None:
-        p = shard.materialize(p)  # OPT(fsdp): ZeRO weight gather
-    h = apply_norm(cfg, x, p.get("norm1"))
-    if decode:
-        out, new_state = mamba2_decode(cfg, h, p["ssm"], state, shard)
-    else:
-        out, new_state = mamba2_forward(cfg, h, p["ssm"], shard, initial=state)
-    x = x + out
-    if shard is not None:
-        x = shard.hidden(x)
-    return x, new_state
+    return x, new_kv, new_state, aux
 
 
 # ---------------------------------------------------------------------------
@@ -471,9 +510,14 @@ class Model:
             emb = params["embed"]["tok"].astype(dtype)
             x = self._tok_embed(emb, tok)
             positions = jnp.arange(tok.shape[-1])
+        x = self._scale_embed(x)
         if self.shard is not None:
             x = self.shard.hidden(x)
         return x, positions
+
+    def _scale_embed(self, x):
+        m = self.cfg.embedding_multiplier
+        return x if m == 1.0 else x * m
 
     def unembed(self, params, x) -> jax.Array:
         cfg = self.cfg
@@ -485,6 +529,8 @@ class Model:
             logits = x @ params["embed"]["tok"].astype(x.dtype).T
         else:
             logits = x @ params["lm_head"]["w"].astype(x.dtype)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if self.comm is not None and logits.shape[-1] != cfg.vocab_size:
             # vocab-parallel logits: gather shards on the sampling stream
             logits = self.comm.all_gather(logits, "sample",
@@ -497,107 +543,149 @@ class Model:
     def forward(self, params, batch, *, cache: Optional[DecodeCache] = None,
                 start: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[DecodeCache]]:
-        """Returns (logits, aux, new_cache). ``cache`` non-None => prefill.
+        """Returns (logits, aux, new_cache). ``cache`` non-None => prefill
+        into a fresh cache.
 
         ``start`` — (B,) int32 left-pad lengths for mixed-length prefill:
         row ``b``'s real tokens occupy positions ``[start[b], S)``; pad
-        positions are masked out of attention and RoPE positions are shifted
-        so each row computes exactly what it would alone (attention archs
-        only — SSM state offers no per-row mask).
+        positions are masked out of attention, RoPE positions are shifted,
+        and Mamba-2 layers give pad positions no step (``dt = 0``) and a
+        zero conv input, so each row computes exactly what it would alone
+        (models of one mixer per layer; zamba2's shared block has none).
         """
         cfg = self.cfg
         x, positions = self.embed(params, batch)
         if start is not None:
-            if cfg.family in ("ssm", "hybrid"):
+            if cfg.mixers is None:
                 raise NotImplementedError(
-                    "left-padded prefill needs attention masking; SSM "
-                    "recurrent state has no per-row pad mask")
+                    "left-padded prefill needs one mixer per layer; zamba2's "
+                    "shared attention block has no per-row starts")
             # per-row RoPE positions: the first real token sits at 0
             positions = jnp.maximum(positions[None, :] - start[:, None], 0)
         remat = cfg.remat != "none"
 
-        if cfg.family in ("ssm", "hybrid"):
-            x, new_cache = self._ssm_stack(params, x, positions, cache, remat)
+        if cfg.mixers is None:
+            x, new_cache = self._zamba_stack(params, x, positions, cache,
+                                             remat)
             aux: Dict[str, jax.Array] = {}
+        elif self.comm is not None:
+            # VCI streams chain ordering tokens across collectives; a token
+            # updated inside a lax.scan body would leak its tracer, so the
+            # comm-mode (inference) stack unrolls the layer loop.
+            x, aux, new_cache = self._attn_stack_unrolled(
+                params, x, positions, cache, start)
         else:
-            x, aux, new_cache = self._attn_stack(params, x, positions, cache,
-                                                 remat, start=start)
+            x, aux, new_cache = self._layers(params, x, positions, cache,
+                                             remat, decode=False, start=start)
 
         logits = self.unembed(params, x)
         return logits, aux, new_cache
 
-    def _attn_stack(self, params, x, positions, cache, remat, start=None):
+    def _layers(self, params, x, positions, cache, remat, *, decode: bool,
+                start=None):
+        """The layer loop of every model of one mixer per layer, for
+        training, prefill and decode: a scan over periods of the mixer
+        pattern (``cfg.period``: one layer for dense, MoE and SSM models,
+        ten for granite-4.0-h), the period's layers unrolled in its body.
+
+        The caches ride in the carry beside ``x``: the stacked K/V (the
+        paged pool, or the contiguous cache), which each attention layer
+        writes at its index, and the stacked Mamba-2 state, which each
+        Mamba-2 layer reads and writes at its index. So XLA keeps one buffer
+        of each and updates it in place; nothing is mapped through the
+        scan's inputs and outputs, which would copy the whole stack. The
+        page table and write cursor are shared by every attention layer."""
         cfg = self.cfg
-        if self.comm is not None:
-            # VCI streams chain ordering tokens across collectives; a token
-            # updated inside a lax.scan body would leak its tracer, so the
-            # comm-mode (inference) stack unrolls the layer loop.
-            return self._attn_stack_unrolled(params, x, positions, cache,
-                                             start)
-        if cache is not None and isinstance(cache.kv, PagedKVCache):
-            return self._attn_stack_paged(params, x, positions, cache, remat,
-                                          decode=False, start=start)
+        per = cfg.period
+        pattern = cfg.mixers[:per]
+        n_per = cfg.num_layers // per
+        # layers of each kind in a period; norms and MLPs are in every layer
+        k_of = {"attn": pattern.count("attention"),
+                "ssm": pattern.count("mamba")}
+
+        def count(name):  # layers of a stack in each period
+            return k_of.get(name, per)
+
+        # stacks with one layer a period are scanned; the others are held
+        # whole and indexed in place (a reshape into periods would copy a
+        # stack whose device layout is not row-major)
+        scan_layers = {n: t for n, t in params["layers"].items()
+                       if count(n) == 1}
+        held = {n: t for n, t in params["layers"].items() if count(n) > 1}
+        kv = None if cache is None else cache.kv
+        st = None if cache is None else cache.ssm
+        paged = isinstance(kv, PagedKVCache)
+        indexed = cache is not None or bool(held)
 
         def body(carry, scanned):
-            x = carry
-            if cache is not None:
-                lp, kv = scanned
-            else:
-                lp, kv = scanned, None
-            x, new_kv, aux = _dense_block(cfg, x, lp, positions, self.shard,
-                                          kv=kv, decode=False, comm=self.comm,
-                                          start=start)
-            aux_vec = jnp.stack([aux.get("load_balance", jnp.zeros(())),
-                                 aux.get("router_z", jnp.zeros(()))])
-            return x, (new_kv, aux_vec)
+            x, kvs, sts = carry
+            lp, p = scanned if indexed else (scanned, None)
+            seen = {"attention": 0, "mamba": 0}
+            auxes = []
+            for j, mixer in enumerate(pattern):
+                i = seen[mixer]
+                seen[mixer] += 1
+                own = "attn" if mixer == "attention" else "ssm"
+                lj = {}
+                for n in params["layers"]:
+                    if n in k_of and n != own:
+                        continue
+                    c, idx = count(n), (i if n == own else j)
+                    lj[n] = lp[n] if c == 1 else jax.tree_util.tree_map(
+                        lambda a: a[p * c + idx], held[n])
+                k = k_of[own]  # l: the layer's index in its kind's stack
+                l = p if k == 1 or p is None else p * k + i
+                if mixer == "attention":
+                    view = None
+                    if kv is not None:
+                        kk, vv = kvs
+                        view = (PagedKVLayer(kk, vv, kv.table, kv.length, l,
+                                             kv.page_size) if paged
+                                else KVCache(kk[l], vv[l], kv.length,
+                                             kv.ring))
+                    x, new_kv, _, aux = _block(
+                        cfg, x, lj, positions, self.shard, kv=view,
+                        decode=decode, comm=self.comm, start=start)
+                    if kv is not None:
+                        kvs = ((new_kv.k, new_kv.v) if paged
+                               else (kk.at[l].set(new_kv.k),
+                                     vv.at[l].set(new_kv.v)))
+                else:
+                    state = None
+                    if decode:
+                        state = SSMState(sts[0][l], sts[1][l])
+                    x, _, new_st, aux = _block(
+                        cfg, x, lj, positions, self.shard, mixer="mamba",
+                        state=state, decode=decode, start=start)
+                    if st is not None:
+                        sts = (sts[0].at[l].set(
+                                   new_st.conv.astype(sts[0].dtype)),
+                               sts[1].at[l].set(new_st.ssd))
+                auxes.append(jnp.stack([
+                    aux.get("load_balance", jnp.zeros(())),
+                    aux.get("router_z", jnp.zeros(()))]))
+            return (x, kvs, sts), functools.reduce(operator.add, auxes)
 
         if remat:
             body = jax.checkpoint(body, policy=_remat_policy(cfg))
+        kvs = () if kv is None else (kv.k, kv.v)
+        sts = () if st is None else (st.conv, st.ssd)
+        scanned = scan_layers
+        if indexed:
+            scanned = (scan_layers, jnp.arange(n_per, dtype=jnp.int32))
+        (x, kvs, sts), aux_v = jax.lax.scan(body, (x, kvs, sts), scanned)
+        new_cache = None
         if cache is not None:
-            kv_stack = KVCache(cache.kv.k, cache.kv.v,
-                               jnp.broadcast_to(cache.kv.length, (cfg.num_layers,)),
-                               cache.kv.ring)
-            x, (kv_out, aux_v) = jax.lax.scan(body, x, (params["layers"], kv_stack))
-            new_cache = DecodeCache(
-                KVCache(kv_out.k, kv_out.v,
-                        cache.kv.length + x.shape[1], cache.kv.ring),
-                None, cache.length + x.shape[1])
-        else:
-            x, (_, aux_v) = jax.lax.scan(body, x, params["layers"])
-            new_cache = None
-        aux = {"load_balance": aux_v[:, 0].sum(), "router_z": aux_v[:, 1].sum()}
-        return x, aux, new_cache
-
-    def _attn_stack_paged(self, params, x, positions, cache, remat, *,
-                          decode: bool, start=None):
-        """The layer scan over the paged pool, for prefill and decode: the
-        stacked K/V pools ride in the carry beside ``x``, each layer
-        scatters its rows into them and the page gather reads them where
-        they lie, so XLA keeps one pool buffer and updates it in place. The
-        page table and write cursor are shared by every layer."""
-        cfg = self.cfg
-        pk = cache.kv
-
-        def body(carry, scanned):
-            x, k, v = carry
-            lp, l = scanned
-            layer = PagedKVLayer(k, v, pk.table, pk.length, l, pk.page_size)
-            x, new_kv, aux = _dense_block(cfg, x, lp, positions, self.shard,
-                                          kv=layer, decode=decode,
-                                          comm=self.comm, start=start)
-            aux_vec = jnp.stack([aux.get("load_balance", jnp.zeros(())),
-                                 aux.get("router_z", jnp.zeros(()))])
-            return (x, new_kv.k, new_kv.v), aux_vec
-
-        if remat:
-            body = jax.checkpoint(body, policy=_remat_policy(cfg))
-        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        (x, k, v), aux_v = jax.lax.scan(body, (x, pk.k, pk.v),
-                                        (params["layers"], layers))
-        s_new = x.shape[1]
-        new_cache = DecodeCache(
-            PagedKVCache(k, v, pk.table, pk.length + s_new, pk.page_size),
-            None, cache.length + s_new)
+            s_new = x.shape[1]
+            new_kv = None
+            if paged:
+                new_kv = PagedKVCache(*kvs, kv.table, kv.length + s_new,
+                                      kv.page_size)
+            elif kv is not None:
+                new_kv = KVCache(*kvs, kv.length + s_new, kv.ring)
+            new_cache = DecodeCache(new_kv, None if st is None
+                                    else SSMState(*sts),
+                                    cache.length + s_new)
         aux = {"load_balance": aux_v[:, 0].sum(), "router_z": aux_v[:, 1].sum()}
         return x, aux, new_cache
 
@@ -609,7 +697,7 @@ class Model:
         lb = rz = jnp.zeros(())
         for l in range(cfg.num_layers):
             lp = take(lambda a: a[l], params["layers"])
-            x, new_kv, aux = _dense_block(
+            x, new_kv, _, aux = _block(
                 cfg, x, lp, positions, None,
                 kv=None if kv is None else _layer_kv(kv, l), decode=False,
                 comm=self.comm, start=start)
@@ -623,31 +711,24 @@ class Model:
                                     cache.length + x.shape[1])
         return x, {"load_balance": lb, "router_z": rz}, new_cache
 
-    def _ssm_stack(self, params, x, positions, cache, remat):
+    def _zamba_stack(self, params, x, positions, cache, remat):
+        """zamba2: groups of ``hybrid_attn_every`` Mamba-2 blocks, each
+        group followed by the shared attention block (its own KV cache per
+        site), then the remaining blocks."""
         cfg = self.cfg
         k = cfg.hybrid_attn_every
         L = cfg.num_layers
 
         def ssm_body(carry, scanned):
             x = carry
-            if cache is not None:
-                lp, st = scanned
-            else:
-                lp, st = scanned, None
-            x, new_st = _ssm_block(cfg, x, lp, self.shard, state=st, decode=False)
+            lp = scanned[0] if cache is not None else scanned
+            x, _, new_st, _ = _block(cfg, x, lp, positions, self.shard,
+                                     mixer="mamba")
             return x, new_st
 
         if remat:
             ssm_body = jax.checkpoint(ssm_body, policy=_remat_policy(cfg))
 
-        if cfg.family == "ssm":
-            if cache is not None:
-                x, st_out = jax.lax.scan(ssm_body, x, (params["layers"], cache.ssm))
-                return x, DecodeCache(None, st_out, cache.length + x.shape[1])
-            x, _ = jax.lax.scan(ssm_body, x, params["layers"])
-            return x, None
-
-        # ---- hybrid: groups of k ssm blocks + shared attention --------------
         n_groups, rem = divmod(L, k)
         lp_all = params["layers"]
         take = jax.tree_util.tree_map
@@ -728,10 +809,11 @@ class Model:
                                  out_axes=1)(emb, tokens), axis=1)
         else:
             x = self._tok_embed(params["embed"]["tok"].astype(dtype), tokens)
+        x = self._scale_embed(x)
         if start is not None:
-            if cfg.family in ("ssm", "hybrid"):
+            if cfg.mixers is None:
                 raise NotImplementedError(
-                    "per-row start offsets need attention masking")
+                    "per-row start offsets need one mixer per layer")
             positions = (cache.length - start)[:, None]
         else:
             positions = cache.length[None, None] + jnp.zeros(
@@ -739,52 +821,31 @@ class Model:
         if self.shard is not None:
             x = self.shard.hidden(x)
 
-        if cfg.family in ("ssm", "hybrid"):
-            x, new_cache = self._decode_ssm(params, x, positions, cache)
+        if cfg.mixers is None:
+            x, new_cache = self._zamba_decode(params, x, positions, cache)
+        elif self.comm is not None:
+            x, new_cache = self._decode_unrolled(params, x, positions, cache,
+                                                 start=start)
         else:
-            x, new_cache = self._decode_attn(params, x, positions, cache,
-                                             start=start)
+            x, _, new_cache = self._layers(params, x, positions, cache, False,
+                                           decode=True, start=start)
         logits = self.unembed(params, x)
         return logits, new_cache
 
-    def _decode_attn(self, params, x, positions, cache, start=None):
+    def _decode_unrolled(self, params, x, positions, cache, start=None):
+        """Python-loop decode for the comm path: see _attn_stack_unrolled."""
         cfg = self.cfg
-        if self.comm is not None:  # unrolled: see _attn_stack_unrolled
-            take = jax.tree_util.tree_map
-            kv = cache.kv
-            for l in range(cfg.num_layers):
-                lp = take(lambda a: a[l], params["layers"])
-                x, new_kv, _ = _dense_block(cfg, x, lp, positions, None,
-                                            kv=_layer_kv(kv, l), decode=True,
-                                            comm=self.comm, start=start)
-                kv = _set_layer_kv(kv, l, new_kv)
-            new_cache = DecodeCache(_advance_kv(kv, 1), None,
-                                    cache.length + 1)
-            return x, new_cache
+        take = jax.tree_util.tree_map
+        kv = cache.kv
+        for l in range(cfg.num_layers):
+            lp = take(lambda a: a[l], params["layers"])
+            x, new_kv, _, _ = _block(cfg, x, lp, positions, None,
+                                     kv=_layer_kv(kv, l), decode=True,
+                                     comm=self.comm, start=start)
+            kv = _set_layer_kv(kv, l, new_kv)
+        return x, DecodeCache(_advance_kv(kv, 1), None, cache.length + 1)
 
-        if isinstance(cache.kv, PagedKVCache):
-            x, _, new_cache = self._attn_stack_paged(
-                params, x, positions, cache, False, decode=True, start=start)
-            return x, new_cache
-
-        def body(carry, scanned):
-            x = carry
-            lp, kv = scanned
-            x, new_kv, _ = _dense_block(cfg, x, lp, positions, self.shard,
-                                        kv=kv, decode=True, comm=self.comm,
-                                        start=start)
-            return x, new_kv
-
-        kv_stack = KVCache(cache.kv.k, cache.kv.v,
-                           jnp.broadcast_to(cache.kv.length, (cfg.num_layers,)),
-                           cache.kv.ring)
-        x, kv_out = jax.lax.scan(body, x, (params["layers"], kv_stack))
-        new_cache = DecodeCache(
-            KVCache(kv_out.k, kv_out.v, cache.kv.length + 1, cache.kv.ring),
-            None, cache.length + 1)
-        return x, new_cache
-
-    def _decode_ssm(self, params, x, positions, cache):
+    def _zamba_decode(self, params, x, positions, cache):
         cfg = self.cfg
         k = cfg.hybrid_attn_every
         L = cfg.num_layers
@@ -793,12 +854,9 @@ class Model:
         def ssm_body(carry, scanned):
             x = carry
             lp, st = scanned
-            x, new_st = _ssm_block(cfg, x, lp, self.shard, state=st, decode=True)
+            x, _, new_st, _ = _block(cfg, x, lp, positions, self.shard,
+                                     mixer="mamba", state=st, decode=True)
             return x, new_st
-
-        if cfg.family == "ssm":
-            x, st_out = jax.lax.scan(ssm_body, x, (params["layers"], cache.ssm))
-            return x, DecodeCache(None, st_out, cache.length + 1)
 
         n_groups, rem = divmod(L, k)
         lp_all = params["layers"]

@@ -245,8 +245,10 @@ def serve_cache_specs(cache, tp: int, batch_shards: int, *,
 
     kv = getattr(cache, "kv", None)
     if isinstance(kv, PagedKVCache):
+        # (L, NP, PS, KV, hd), or (L, NP, PS, KV*hd) for heads under 128:
+        # a row splits at head boundaries, the TP path holding KV % tp == 0
         kv_ax = axis if (tp > 1 and kv.k.shape[3] % tp == 0) else None
-        pool = P(None, None, None, kv_ax, None)   # (L, NP, PS, KV, hd)
+        pool = P(None, None, None, kv_ax, *[None] * (kv.k.ndim - 4))
         kv_spec = PagedKVCache(pool, pool, P(), P(), kv.page_size)
         rest = jax.tree_util.tree_map(assign, cache.ssm)
         return type(cache)(kv_spec, rest, P())

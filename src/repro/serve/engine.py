@@ -29,9 +29,13 @@ moment it finishes, and mid-stream admission works under a mesh because
 the admitted request prefills into freshly allocated pages under the same
 TP specs as the running batch. See the :class:`ServeEngine` docstring.
 
-Architectures whose decode state cannot be pad-masked per row (SSM/hybrid
-recurrences, ring caches, VLM/audio frontends) fall back to equal-length
-grouped batches — same results, no corruption, just less packing.
+Text models of one mixer per layer take the continuous path: dense, MoE,
+SSM and the per-layer pattern hybrids (granite-4.0-h), whose Mamba-2 layers
+keep a recurrent state per slot beside the attention layers' K/V. Left-pad
+positions give a Mamba-2 layer no step, and admission writes the slot's
+state row. Ring caches, zamba2's shared attention block and the VLM/audio
+frontends fall back to equal-length grouped batches — same results, no
+corruption, just less packing.
 """
 
 from __future__ import annotations
@@ -49,6 +53,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import Sharder, batch_axes
 from repro.models.attention import KVCache, PagedKVCache, paged_splice
+from repro.models.ssm import SSMState
 from repro.models.transformer import (
     DecodeCache,
     Model,
@@ -292,8 +297,11 @@ class ServeEngine:
       the running batch (the contiguous engine can only splice-admit
       single-device).
 
-    Ring (sliding-window) and SSM/hybrid/audio/VLM caches have no paged
-    layout; those keep the grouped equal-length contiguous fallback.
+    The pages hold the attention layers' K/V; a Mamba-2 layer's state has
+    no positions and lives beside them, one row per slot, in the same
+    :class:`~repro.models.transformer.DecodeCache`. Ring (sliding-window)
+    caches, zamba2's shared attention block and audio/VLM frontends have no
+    paged layout; those keep the grouped equal-length contiguous fallback.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, batch_size: int,
@@ -331,13 +339,14 @@ class ServeEngine:
         self._nkey = 0
         self._ring = (cfg.sliding_window is not None
                       and cfg.sliding_window < max_len)
-        # left-padded mixed-length batching needs per-row attention masks;
-        # SSM/hybrid state, ring caches and non-text frontends can't provide
-        # them -> equal-length grouped batches for those.
-        self._padded_ok = (cfg.family in ("dense", "moe")
-                           and cfg.modality == "text" and not self._ring)
-        # paged cache: attention archs on the continuous path only; other
-        # families keep the grouped contiguous fallback.
+        # left-padded mixed-length batching needs every layer's state to
+        # take per-row starts: attention masks, Mamba-2 steps skipped. Ring
+        # caches, zamba2's shared block and non-text frontends can't ->
+        # equal-length grouped batches for those.
+        self._padded_ok = (cfg.mixers is not None and cfg.modality == "text"
+                           and not self._ring)
+        # paged cache: the continuous path only; the grouped fallback keeps
+        # the contiguous cache.
         self._paged = bool(paged) and self._padded_ok
         self._page_size = int(page_size)
         self._max_pages = -(-max_len // self._page_size)
@@ -627,8 +636,9 @@ class ServeEngine:
 
     def _admit(self, r: Request, cache, slot: int, cur: int):
         """Prefill ``r`` alone and splice its KV rows into ``slot``'s cache
-        at virtual positions ``[cur - plen, cur)``; returns (first token,
-        cache). Contiguous: a dynamic_update_slice into the slot's row,
+        at virtual positions ``[cur - plen, cur)``, and its Mamba-2 state
+        into ``slot``'s state row; returns (first token, cache).
+        Contiguous: a dynamic_update_slice into the slot's row,
         single-device only. Paged: a page-table splice into the slot's
         freshly allocated pages — under a mesh the prefill runs replicated
         over the data axes with the running batch's TP specs, the
@@ -669,18 +679,27 @@ class ServeEngine:
                 logits, _, tmp = model.forward(params, {"tokens": tokens},
                                                cache=tmp, start=start1)
                 nxt = select_tokens(_last_logits(cfg, logits), temp1, key)
-                if paged:
-                    kv = paged_splice(cache.kv, slot, dest,
+                kv = cache.kv
+                if tmp.kv is None:  # no attention layers
+                    pass
+                elif paged:
+                    kv = paged_splice(kv, slot, dest,
                                       tmp.kv.k[:, 0], tmp.kv.v[:, 0])
                 else:
                     k = jax.lax.dynamic_update_slice(
-                        cache.kv.k, tmp.kv.k.astype(cache.kv.k.dtype),
+                        kv.k, tmp.kv.k.astype(kv.k.dtype),
                         (0, slot, dest, 0, 0))
                     v = jax.lax.dynamic_update_slice(
-                        cache.kv.v, tmp.kv.v.astype(cache.kv.v.dtype),
+                        kv.v, tmp.kv.v.astype(kv.v.dtype),
                         (0, slot, dest, 0, 0))
-                    kv = KVCache(k, v, cache.kv.length, cache.kv.ring)
-                return nxt, DecodeCache(kv, cache.ssm, cache.length)
+                    kv = KVCache(k, v, kv.length, kv.ring)
+                ssm = cache.ssm
+                if ssm is not None:  # the slot's Mamba-2 state row
+                    ssm = SSMState(
+                        ssm.conv.at[:, slot].set(
+                            tmp.ssm.conv[:, 0].astype(ssm.conv.dtype)),
+                        ssm.ssd.at[:, slot].set(tmp.ssm.ssd[:, 0]))
+                return nxt, DecodeCache(kv, ssm, cache.length)
 
             fn = jax.jit(admit, donate_argnums=(2,))
         self._admit_fns[p_adm] = fn
@@ -736,7 +755,7 @@ class ServeEngine:
         self._note_cache(cache)
         temps = np.asarray([self._temp_of(r) for r in reqs], np.float32)
         # comm-mode step functions take concrete (all-zero) start offsets;
-        # the plain path keeps None (SSM/audio reject per-row offsets).
+        # the plain path keeps None (zamba2/audio reject per-row offsets).
         start = (None if self.comm_plan is None
                  else jnp.zeros((b,), jnp.int32))
         nxt, cache = self._prefill(

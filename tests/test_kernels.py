@@ -176,6 +176,36 @@ class TestSSDKernel:
         np.testing.assert_allclose(y8, y20, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(st8, st20, rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("impl", ["xla", "pallas"])
+    def test_strong_decay_at_chunk_256_stays_finite(self, impl):
+        """At chunk 256 with strong decay, exp(cum_i - cum_j) above the
+        diagonal overflows; it is masked before the exp, so the forward is
+        finite and the gradient has no NaN (a mask after the exp passes
+        inf * 0 = NaN back)."""
+        b, s, h, p, n, g = 1, 256, 2, 8, 4, 1
+        ks = jax.random.split(jax.random.PRNGKey(6), 5)
+        x = jax.random.normal(ks[0], (b, s, h, p))
+        dt = jnp.full((b, s, h), 0.5)
+        A = jnp.asarray([-4.0, -16.0])     # cum falls by up to 2,048
+        B = jax.random.normal(ks[3], (b, s, g, n)) * 0.3
+        C = jax.random.normal(ks[4], (b, s, g, n)) * 0.3
+        assert float(jnp.exp(-jnp.cumsum(dt * A[None, None], 1)).max()) \
+            == float("inf")
+        if impl == "pallas":
+            y, st = ssd_chunked(x, dt, A, B, C, chunk=256, interpret=True)
+            assert jnp.isfinite(y).all() and jnp.isfinite(st).all()
+            return
+
+        def loss(x, dt, A, B, C):
+            y, st = ssm_mod.ssd_chunked(x, dt, A, B, C, chunk=256)
+            return jnp.sum(y ** 2) + jnp.sum(st ** 2)
+
+        val, grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
+            x, dt, A, B, C)
+        assert jnp.isfinite(val)
+        for gr in grads:
+            assert not jnp.isnan(gr).any()
+
 
 class TestRowGather:
     @pytest.mark.parametrize("rows,d", [(16, 64), (64, 128), (8, 512)])
@@ -278,6 +308,24 @@ class TestPagedGather:
         assert out_k.shape == (b, maxp * ps, kv, hd)
         np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
         np.testing.assert_array_equal(np.asarray(out_t), np.asarray(out_r))
+
+    @pytest.mark.parametrize("l", [0, LAYERS - 1])
+    def test_row_pool_matches_oracle(self, l):
+        # the (L, NP, PS, KV*hd) pool of heads narrower than a lane tile:
+        # each token's heads one row, gathered as they lie
+        from repro.kernels.paged_kv import (
+            paged_gather_pallas, paged_gather_ref, paged_gather_take)
+        b, maxp, np_pages, ps, e = 3, 4, 16, 8, 16
+        rng = np.random.default_rng(7)
+        pool = jnp.asarray(rng.normal(size=(self.LAYERS, np_pages, ps, e)),
+                           jnp.float32)
+        table = jnp.asarray(self._tables(rng, b, maxp, np_pages))
+        layer = jnp.asarray(l, jnp.int32)
+        out_r = paged_gather_ref(pool[l], table)
+        assert out_r.shape == (b, maxp * ps, e)
+        for out in (paged_gather_pallas(pool, table, layer, interpret=True),
+                    paged_gather_take(pool, table, layer)):
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(out_r))
 
     def test_unmapped_pages_zero(self):
         from repro.kernels.paged_kv import (

@@ -86,6 +86,18 @@ def test_paged_gather(one_chip, dtype):
     assert custom_call_results(hlo) == [f"{short}[{b * maxp},{ps},{e}]"]
 
 
+def test_paged_gather_narrow_heads(one_chip):
+    # granite-4.0-h-micro's pool (4 attention layers, 8 KV heads of 64, kept
+    # as one row of 512 a token) at the granite cell's batch 16, max_len 2048
+    cfg = get_config("granite-4.0-h-micro")
+    ps, maxp, b = 16, 128, 16
+    e = cfg.num_kv_heads * cfg.head_dim
+    pool = (4, 1 + b * maxp, ps, e)
+    hlo = compile_text(paged_gather_pallas, one_chip, (pool, jnp.float32),
+                       ((b, maxp), jnp.int32), ((), jnp.int32))
+    assert custom_call_results(hlo) == [f"f32[{b * maxp},{ps},{e}]"]
+
+
 def test_paged_serve_step_keeps_pool_in_place(one_chip, monkeypatch):
     # the olmo-1b decode step at the chat cell's sizes (batch 16, max_len
     # 256, pages of 16, float32 pool), cache donated as the engine does:
