@@ -9,7 +9,8 @@ One chip:
 * serve: olmo-1b at its published widths and full depth through
   ``ServeEngine.generate``, with the paged and the contiguous KV cache, batch
   4 and 8 requests so that finished slots take new requests mid-stream. The
-  paged decode step must hold the Pallas page gather (``tpu_custom_call``);
+  paged decode step must hold the Pallas paged attention kernel
+  (``tpu_custom_call``);
   a full forward over each finished sequence must be finite and pick the
   same tokens wherever its top-2 margin is wider than ``LOGIT_BOUND``; the
   two layouts' tokens must be identical up to the first position whose
@@ -269,8 +270,8 @@ def serve_one_chip(cfg, clock: CompileClock) -> None:
                 jax.random.PRNGKey(0)).compile().as_text()
             n = hlo.count("tpu_custom_call")
             say(f"[serve paged] decode step: {n} tpu_custom_call mentions")
-            check(n > 0, "serve paged: the decode step runs the Pallas page "
-                         "gather")
+            check(n > 0, "serve paged: the decode step runs the Pallas paged "
+                         "attention kernel")
         report_memory(label)
     # One full forward over both layouts' sequences.
     c0 = clock.secs
@@ -283,8 +284,8 @@ def serve_one_chip(cfg, clock: CompileClock) -> None:
     n = len(reqs)
     check_against_forward("serve paged", picks[:n], toks[True])
     check_against_forward("serve contiguous", picks[n:], toks[False])
-    # Not bitwise: XLA lays out the gathered page view and the contiguous
-    # cache differently, so the q.k and softmax sums run in another order.
+    # Not bitwise: the paged kernel's online softmax and the contiguous
+    # cache's attention run the q.k and softmax sums in another order.
     check_same_greedy("serve paged vs contiguous", picks[n:], toks[False],
                       toks[True])
 

@@ -1,10 +1,10 @@
 """Attention: GQA/MQA, causal + sliding-window masks, KV-cache decode.
 
-Attention is computed by the XLA code below on every backend. The Pallas
-flash-attention kernel (``repro.kernels.ops.flash_attention``, tested
-against ``repro.kernels.ref``) is not called by the model. The paged decode
-path gathers its pages with the Pallas kernel of
-:mod:`repro.kernels.paged_kv` on TPU.
+Attention is computed by the XLA code below, but for the paged decode step
+on TPU, which attends with the Pallas kernel of
+:mod:`repro.kernels.paged_kv`. The Pallas flash-attention kernel
+(``repro.kernels.ops.flash_attention``, tested against
+``repro.kernels.ref``) is not called by the model.
 
 Decode supports two cache layouts:
 
@@ -339,16 +339,22 @@ def paged_splice(cache: PagedKVCache, slot, dest, k_rows, v_rows
 
 def paged_decode_attention(cfg: ModelConfig, q, layer: PagedKVLayer,
                            start: Optional[jax.Array] = None) -> jax.Array:
-    """One-token attention against the paged cache: gather each slot's
-    pages of this layer into sequence order (Pallas tile-gather on TPU, one
-    gather elsewhere — :mod:`repro.kernels.paged_kv`), then the standard masked
-    decode attention. Validity is identical to the contiguous layout —
-    ``[start, length)`` — which is what makes paged-vs-contiguous token
-    equality exact rather than approximate."""
-    from repro.kernels.paged_kv import paged_gather
-    heads = (-1, q.shape[-1])  # a merged row of heads parts again
-    k_view = paged_gather(layer.k, layer.table, layer.layer)
-    v_view = paged_gather(layer.v, layer.table, layer.layer)
+    """One-token attention against the paged cache, over ``[start,
+    length)`` as on the contiguous layout. On TPU the Pallas kernel of
+    :mod:`repro.kernels.paged_kv` reads each slot's live pages where they
+    lie in the stacked pool; elsewhere each slot's pages of this layer are
+    gathered into sequence order for the standard masked decode
+    attention."""
+    from repro.kernels import paged_kv
+    hd = q.shape[-1]
+    if paged_kv._on_tpu():
+        o = paged_kv.paged_decode_attention_pallas(
+            q[:, 0], layer.k, layer.v, layer.table, start, layer.length,
+            layer.layer, scale=softmax_scale(cfg, hd))
+        return o[:, None]
+    heads = (-1, hd)  # a merged row of heads parts again
+    k_view = paged_kv.paged_gather_take(layer.k, layer.table, layer.layer)
+    v_view = paged_kv.paged_gather_take(layer.v, layer.table, layer.layer)
     k_view = k_view.reshape(k_view.shape[:2] + heads)
     v_view = v_view.reshape(v_view.shape[:2] + heads)
     view = KVCache(k_view, v_view, layer.length, ring=False)

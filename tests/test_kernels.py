@@ -266,9 +266,9 @@ class TestBucketPack:
 
 
 class TestPagedGather:
-    """Paged-KV page gather: Pallas kernel (interpret mode) vs the jnp.take
-    lowering vs the scalar oracle, over pool shapes and table patterns
-    (unmapped entries, shared-nothing ownership, out-of-order pages)."""
+    """Paged-KV page gather: the jnp.take lowering vs the scalar oracle,
+    over pool shapes and table patterns (unmapped entries, shared-nothing
+    ownership, out-of-order pages)."""
 
     def _tables(self, rng, b, maxp, np_pages):
         # mapped entries draw WITHOUT replacement (allocator invariant:
@@ -294,27 +294,23 @@ class TestPagedGather:
     def test_matches_oracle(self, b, maxp, np_pages, ps, kv, hd, l):
         # the stacked (L, NP, PS, KV, hd) pool, read at layer l: the same
         # pages as the oracle's gather of that layer's pool alone
-        from repro.kernels.paged_kv import (
-            paged_gather_pallas, paged_gather_ref, paged_gather_take)
+        from repro.kernels.paged_kv import paged_gather_ref, paged_gather_take
         rng = np.random.default_rng(b * 100 + maxp)
         pool = jnp.asarray(
             rng.normal(size=(self.LAYERS, np_pages, ps, kv, hd)),
             jnp.float32)
         table = jnp.asarray(self._tables(rng, b, maxp, np_pages))
         layer = jnp.asarray(l, jnp.int32)
-        out_k = paged_gather_pallas(pool, table, layer, interpret=True)
         out_t = paged_gather_take(pool, table, layer)
         out_r = paged_gather_ref(pool[l], table)
-        assert out_k.shape == (b, maxp * ps, kv, hd)
-        np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+        assert out_t.shape == (b, maxp * ps, kv, hd)
         np.testing.assert_array_equal(np.asarray(out_t), np.asarray(out_r))
 
     @pytest.mark.parametrize("l", [0, LAYERS - 1])
     def test_row_pool_matches_oracle(self, l):
         # the (L, NP, PS, KV*hd) pool of heads narrower than a lane tile:
         # each token's heads one row, gathered as they lie
-        from repro.kernels.paged_kv import (
-            paged_gather_pallas, paged_gather_ref, paged_gather_take)
+        from repro.kernels.paged_kv import paged_gather_ref, paged_gather_take
         b, maxp, np_pages, ps, e = 3, 4, 16, 8, 16
         rng = np.random.default_rng(7)
         pool = jnp.asarray(rng.normal(size=(self.LAYERS, np_pages, ps, e)),
@@ -323,26 +319,22 @@ class TestPagedGather:
         layer = jnp.asarray(l, jnp.int32)
         out_r = paged_gather_ref(pool[l], table)
         assert out_r.shape == (b, maxp * ps, e)
-        for out in (paged_gather_pallas(pool, table, layer, interpret=True),
-                    paged_gather_take(pool, table, layer)):
-            np.testing.assert_array_equal(np.asarray(out), np.asarray(out_r))
+        out = paged_gather_take(pool, table, layer)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out_r))
 
     def test_unmapped_pages_zero(self):
-        from repro.kernels.paged_kv import (
-            paged_gather_pallas, paged_gather_take)
+        from repro.kernels.paged_kv import paged_gather_take
         # every page of layer l holds l + 1, so a zero can only come from
         # the unmapped-entry mask
         pool = (jnp.arange(1, 4, dtype=jnp.float32).reshape(3, 1, 1, 1, 1)
                 * jnp.ones((3, 4, 2, 1, 2), jnp.float32))
         table = jnp.asarray([[-1, 2], [1, -1]], jnp.int32)
         layer = jnp.asarray(1, jnp.int32)
-        for out in (paged_gather_pallas(pool, table, layer, interpret=True),
-                    paged_gather_take(pool, table, layer)):
-            out = np.asarray(out)
-            np.testing.assert_array_equal(out[0, :2], 0.0)   # unmapped
-            np.testing.assert_array_equal(out[0, 2:], 2.0)
-            np.testing.assert_array_equal(out[1, :2], 2.0)
-            np.testing.assert_array_equal(out[1, 2:], 0.0)
+        out = np.asarray(paged_gather_take(pool, table, layer))
+        np.testing.assert_array_equal(out[0, :2], 0.0)   # unmapped
+        np.testing.assert_array_equal(out[0, 2:], 2.0)
+        np.testing.assert_array_equal(out[1, :2], 2.0)
+        np.testing.assert_array_equal(out[1, 2:], 0.0)
 
     def test_update_decode_writes_only_its_rows(self):
         # one decode token at layer l lands in exactly the B rows
@@ -372,3 +364,153 @@ class TestPagedGather:
             got = np.asarray(pool)
             assert got.tobytes() == want.tobytes()
             assert (got != old).any(axis=(3, 4)).sum() == b
+
+
+class TestPagedDecodeAttention:
+    """The paged decode attention kernel (interpret mode) vs the gather
+    and the contiguous decode attention it replaces on TPU, over both pool
+    layouts, GQA groups, pool and query dtypes, layers of the stack, spans
+    that start and end inside a page, and compute blocks of one page row
+    or of two pages (walked across blocks and slots)."""
+
+    LAYERS, PS, MAXP = 3, 4, 7
+
+    def _case(self, rng, layout, groups, pool_dt, q_dt, kv=2, hd=16, b=4):
+        np_pages = 1 + b * self.MAXP
+        tail = (kv, hd) if layout == "heads" else (kv * hd,)
+        shape = (self.LAYERS, np_pages, self.PS) + tail
+        k = jnp.asarray(rng.normal(size=shape), pool_dt)
+        v = jnp.asarray(rng.normal(size=shape), pool_dt)
+        q = jnp.asarray(rng.normal(size=(b, kv * groups, hd)), q_dt)
+        length = 5 * self.PS + 3                      # inside page 5
+        start = np.asarray([1, 6, 9, 0], np.int32)[:b]   # inside pages
+        table = np.full((b, self.MAXP), -1, np.int32)
+        ids = iter(rng.permutation(np_pages - 1) + 1)
+        for i in range(b):
+            for p in range(start[i] // self.PS, self.MAXP):
+                # the span's pages are mapped; a page past the cursor may be
+                if p * self.PS < length or rng.random() < 0.5:
+                    table[i, p] = next(ids)
+        return q, k, v, jnp.asarray(table), jnp.asarray(start), length
+
+    @staticmethod
+    def _oracle(q, k, v, table, start, length, layer, scale):
+        from repro.configs.base import ModelConfig
+        from repro.kernels.paged_kv import paged_gather_take
+        from repro.models.attention import KVCache, decode_attention
+        hd = q.shape[-1]
+        kv = paged_gather_take(k, table, layer)
+        vv = paged_gather_take(v, table, layer)
+        view = KVCache(kv.reshape(kv.shape[:2] + (-1, hd)),
+                       vv.reshape(vv.shape[:2] + (-1, hd)),
+                       jnp.asarray(length, jnp.int32))
+        cfg = ModelConfig(name="t", family="dense", num_layers=1,
+                          d_model=q.shape[1] * hd, num_heads=q.shape[1],
+                          num_kv_heads=1, head_dim=hd, d_ff=1, vocab_size=1,
+                          attention_multiplier=scale)
+        return decode_attention(cfg, q[:, None], view, start=start)[:, 0]
+
+    @pytest.mark.parametrize("blocks", ["row", "two_pages"])
+    @pytest.mark.parametrize("pool_dt,q_dt", [
+        (jnp.float32, jnp.float32), (jnp.float32, jnp.bfloat16),
+        (jnp.bfloat16, jnp.bfloat16)])
+    @pytest.mark.parametrize("groups", [1, 4])
+    @pytest.mark.parametrize("layout", ["heads", "row"])
+    def test_matches_gather_and_decode_attention(
+            self, monkeypatch, layout, groups, pool_dt, q_dt, blocks):
+        # the first, a middle and the last layer of the stack, read by one
+        # compiled kernel that takes the layer at run time
+        from repro.kernels import paged_kv
+        if blocks == "two_pages":
+            monkeypatch.setattr(paged_kv, "BLOCK_TOKENS", 2 * self.PS)
+            monkeypatch.setattr(paged_kv, "BLOCK_BYTES", 0)
+        rng = np.random.default_rng(groups * 10 + len(blocks))
+        q, k, v, table, start, length = self._case(rng, layout, groups,
+                                                   pool_dt, q_dt)
+        scale = 0.3
+        kernel = jax.jit(lambda layer: paged_kv.paged_decode_attention_pallas(
+            q, k, v, table, start, length, layer, scale=scale,
+            interpret=True))
+        # f32 differs from the oracle only in the order of its sums; with
+        # bf16 queries the kernel rounds unnormalised probabilities to
+        # bf16 where the oracle rounds normalised ones
+        tol = 2e-5 if q_dt == jnp.float32 else 2e-2
+        for layer in range(self.LAYERS):
+            got = kernel(jnp.int32(layer))
+            want = self._oracle(q, k, v, table, start, length, layer, scale)
+            assert got.shape == q.shape and got.dtype == q.dtype
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(want, np.float32),
+                                       rtol=tol, atol=tol)
+
+    def test_unmapped_row_returns_zeros(self):
+        # slot 1's row is all -1 (a finished slot): no page of it is live,
+        # and it reads finite zeros where a guarded denominator is 0
+        from repro.kernels.paged_kv import paged_decode_attention_pallas
+        rng = np.random.default_rng(5)
+        q, k, v, table, start, length = self._case(
+            rng, "heads", 2, jnp.float32, jnp.float32)
+        table = table.at[1].set(-1)
+        got = np.asarray(paged_decode_attention_pallas(
+            q, k, v, table, start, length, 1, scale=0.25, interpret=True))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got[1], 0.0)
+        assert (np.abs(got[[0, 2, 3]]).max(axis=(1, 2)) > 0).all()
+
+    def test_pages_outside_the_span_are_never_attended(self):
+        # NaN in every mapped page that lies wholly before a slot's start or
+        # wholly past the cursor, at the layer read: a NaN that reached the
+        # scores or the weighted sum would poison that slot's output
+        from repro.kernels.paged_kv import paged_decode_attention_pallas
+        rng = np.random.default_rng(9)
+        q, k, v, table, start, length = self._case(
+            rng, "row", 2, jnp.float32, jnp.float32)
+        layer, ps = 2, self.PS
+        tab = np.asarray(table).copy()
+        for i in (1, 2):                   # map the page before the start's
+            tab[i, start[i] // ps - 1] = 1 + self.MAXP * 4 + i
+        tab = jnp.asarray(tab)
+        outside = [(i, p) for i in range(tab.shape[0])
+                   for p in range(self.MAXP)
+                   if tab[i, p] >= 0 and ((p + 1) * ps <= start[i]
+                                          or p * ps >= length)]
+        assert len(outside) >= 3
+        k = jnp.pad(k, ((0, 0), (0, 3), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 3), (0, 0), (0, 0)))
+        for i, p in outside:
+            k = k.at[layer, tab[i, p]].set(jnp.nan)
+            v = v.at[layer, tab[i, p]].set(jnp.nan)
+        got = paged_decode_attention_pallas(q, k, v, tab, start, length,
+                                            layer, scale=0.25,
+                                            interpret=True)
+        assert np.isfinite(np.asarray(got)).all()
+        want = self._oracle(q, jnp.nan_to_num(k), jnp.nan_to_num(v), tab,
+                            start, length, layer, 0.25)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_unmapped_page_inside_the_span_is_not_attended(self):
+        # slot 0 loses a page in the middle of its span: only the positions
+        # of [start, length) on mapped pages are attended
+        from repro.kernels.paged_kv import (paged_decode_attention_pallas,
+                                            paged_gather_take)
+        rng = np.random.default_rng(13)
+        q, k, v, table, start, length = self._case(
+            rng, "heads", 2, jnp.float32, jnp.float32)
+        table = table.at[0, 2].set(-1)
+        layer, scale, hd = 1, 0.25, q.shape[-1]
+        got = paged_decode_attention_pallas(q, k, v, table, start, length,
+                                            layer, scale=scale,
+                                            interpret=True)
+        kv = paged_gather_take(k, table, layer).reshape(4, -1, 2, hd)
+        vv = paged_gather_take(v, table, layer).reshape(4, -1, 2, hd)
+        pos = np.arange(kv.shape[1])
+        ok = ((pos[None] >= np.asarray(start)[:, None]) & (pos[None] < length)
+              & np.repeat(np.asarray(table) >= 0, self.PS, axis=1))
+        kv, vv = np.repeat(kv, 2, axis=2), np.repeat(vv, 2, axis=2)
+        s = np.einsum("bhd,bshd->bhs", q, kv) * scale
+        s = np.where(ok[:, None], s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("bhs,bshd->bhd", p / p.sum(-1, keepdims=True), vv)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
+                                   atol=2e-5)

@@ -372,3 +372,66 @@ def test_paged_falls_back_for_ring_cache():
     eng.generate(reqs)
     for r in reqs:
         assert r.generated.shape == (3,)
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b-smoke",
+                                  "granite-4.0-h-micro-smoke"])
+def test_paged_kernel_engine_matches_gather_path(arch, monkeypatch):
+    """The paged engine with its decode attention on the TPU kernel (run
+    in interpret mode) serves the tokens of the gather path, through a
+    batch prefill and an admission into a freed slot, up to a request's
+    first token whose full-forward top-2 margin is within ``NEAR_TIE``:
+    both sides compute in float32 and differ only in the order of their
+    sums."""
+    import functools
+
+    from repro.kernels import paged_kv
+
+    NEAR_TIE = 1e-3
+    cfg = get_config(arch)
+    params = init_params(cfg, jax.random.PRNGKey(2))
+    rng = np.random.default_rng(4)
+    shapes = ((5, 3), (11, 9), (8, 6))      # the third is admitted
+
+    def serve():
+        reqs = [Request(prompt=rng_prompts[i], max_new_tokens=n)
+                for i, (_, n) in enumerate(shapes)]
+        eng = ServeEngine(cfg, params, batch_size=2, max_len=48, paged=True,
+                          page_size=8)
+        eng.generate(reqs)
+        return [r.generated for r in reqs]
+
+    rng_prompts = [rng.integers(0, cfg.vocab_size, (p,), dtype=np.int32)
+                   for p, _ in shapes]
+    admitted = []
+    admit = ServeEngine._admit
+    monkeypatch.setattr(ServeEngine, "_admit", lambda self, r, *a: (
+        admitted.append(r), admit(self, r, *a))[1])
+    want = serve()
+    assert admitted, "no request was admitted into a freed slot"
+    calls = []
+    kernel = paged_kv.paged_decode_attention_pallas
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(paged_kv, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_kv, "paged_decode_attention_pallas",
+                        functools.partial(counted, interpret=True))
+    got = serve()
+    assert calls, "the decode step never reached the kernel"
+    model = Model(cfg)
+    for prompt, a, b in zip(rng_prompts, want, got):
+        assert a.shape == b.shape
+        diff = np.nonzero(a != b)[0]
+        if diff.size == 0:
+            continue
+        d = int(diff[0])
+        seq = np.concatenate([prompt, a[:d]])[None]
+        logits = np.asarray(model.forward(params, {"tokens": seq})[0][0, -1],
+                            np.float32)
+        top2 = np.sort(logits)[-2:]
+        assert top2[1] - top2[0] <= NEAR_TIE, (
+            f"{arch}: token {d} differs at a top-2 margin of "
+            f"{top2[1] - top2[0]}")

@@ -22,7 +22,7 @@ from repro.kernels.bucket_pack import (MAX_TILES_PER_CALL, TILE,
                                        bucket_pack_pallas,
                                        bucket_unpack_pallas)
 from repro.kernels.ops import flash_attention, row_gather, ssd_chunked
-from repro.kernels.paged_kv import paged_gather_pallas
+from repro.kernels.paged_kv import paged_decode_attention_pallas
 
 
 @pytest.fixture(scope="module")
@@ -71,31 +71,57 @@ def custom_call_results(hlo: str) -> list:
                       r"custom_call_target=\"tpu_custom_call\"", hlo)
 
 
+def named_kernel(hlo: str, name: str) -> bool:
+    """Whether a ``tpu_custom_call`` of compiled HLO text is named
+    ``name`` (the ``pallas_call``'s name, which the device trace shows)."""
+    return re.search(rf"%{name}(\.\d+)? = .* custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"", hlo) is not None
+
+
+def compile_paged_attention(sharding, q, pool, table, scale) -> str:
+    """Compiled HLO of the paged decode attention kernel over a pool's
+    shape; the slot starts, the cursor and the layer are run-time
+    operands."""
+    b = q[0][0]
+
+    def fn(q, k, v, table, start, length, layer):
+        return paged_decode_attention_pallas(q, k, v, table, start, length,
+                                             layer, scale=scale)
+
+    return compile_text(fn, sharding, q, pool, pool, table,
+                        ((b,), jnp.int32), ((), jnp.int32), ((), jnp.int32))
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_paged_gather(one_chip, dtype):
-    # the olmo-1b layer-stacked pool a batch-4 engine holds at max_len 320,
-    # read at a layer given as a run-time operand
+    # the paged decode step's attention (which replaced the page gather)
+    # over the olmo-1b layer-stacked pool a batch-16 engine holds at
+    # max_len 1024, in either cache dtype: the one custom call returns the
+    # attention output, not a view of the pages
     cfg = get_config("olmo-1b")
-    ps, maxp, b = 16, 20, 4
-    pool = (cfg.num_layers, 1 + b * maxp, ps, cfg.num_kv_heads,
-            cfg.head_dim)
-    hlo = compile_text(paged_gather_pallas, one_chip, (pool, dtype),
-                       ((b, maxp), jnp.int32), ((), jnp.int32))
-    short = jnp.dtype(dtype).name.replace("float", "f")   # f32, bf16
-    e = cfg.num_kv_heads * cfg.head_dim
-    assert custom_call_results(hlo) == [f"{short}[{b * maxp},{ps},{e}]"]
+    ps, maxp, b = 16, 64, 16
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    pool = (cfg.num_layers, 1 + b * maxp, ps, kv, hd)
+    hlo = compile_paged_attention(one_chip, ((b, h, hd), jnp.bfloat16),
+                                  (pool, dtype), ((b, maxp), jnp.int32),
+                                  hd ** -0.5)
+    assert custom_call_results(hlo) == [f"bf16[{b},{h},{hd}]"]
+    assert named_kernel(hlo, "paged_decode_attention")
 
 
 def test_paged_gather_narrow_heads(one_chip):
-    # granite-4.0-h-micro's pool (4 attention layers, 8 KV heads of 64, kept
-    # as one row of 512 a token) at the granite cell's batch 16, max_len 2048
+    # the same over granite-4.0-h-micro's pool (4 attention layers, 8 KV
+    # heads of 64 kept as one row of 512 a token, 32 query heads) at the
+    # granite cell's batch 16, max_len 2048
     cfg = get_config("granite-4.0-h-micro")
     ps, maxp, b = 16, 128, 16
-    e = cfg.num_kv_heads * cfg.head_dim
-    pool = (4, 1 + b * maxp, ps, e)
-    hlo = compile_text(paged_gather_pallas, one_chip, (pool, jnp.float32),
-                       ((b, maxp), jnp.int32), ((), jnp.int32))
-    assert custom_call_results(hlo) == [f"f32[{b * maxp},{ps},{e}]"]
+    h, hd = cfg.num_heads, cfg.head_dim
+    pool = (4, 1 + b * maxp, ps, cfg.num_kv_heads * hd)
+    hlo = compile_paged_attention(one_chip, ((b, h, hd), jnp.bfloat16),
+                                  (pool, jnp.float32), ((b, maxp), jnp.int32),
+                                  cfg.attention_multiplier)
+    assert custom_call_results(hlo) == [f"bf16[{b},{h},{hd}]"]
+    assert named_kernel(hlo, "paged_decode_attention")
 
 
 def test_paged_serve_step_keeps_pool_in_place(one_chip, monkeypatch):
@@ -125,8 +151,12 @@ def test_paged_serve_step_keeps_pool_in_place(one_chip, monkeypatch):
     compiled = step.lower(params, tokens, cache, start, temps, key).compile()
     layer_pool = num_pages * ps * cfg.num_kv_heads * cfg.head_dim * 4
     assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
-    view = f"f32[{b * max_len // ps},{ps},{cfg.num_kv_heads * cfg.head_dim}]"
-    assert custom_call_results(compiled.as_text()) == [view, view]
+    # no custom call returns a view of the pages: the one in the step is
+    # the paged attention kernel, which returns the attention output
+    hlo = compiled.as_text()
+    out = f"bf16[{b},{cfg.num_heads},{cfg.head_dim}]"
+    assert custom_call_results(hlo) == [out]
+    assert named_kernel(hlo, "paged_decode_attention")
 
 
 @pytest.mark.parametrize("kernel", [bucket_pack_pallas, bucket_unpack_pallas])
