@@ -14,12 +14,12 @@ depth (the structural metric that transfers to the TPU target), and the
 realized pool statistics.
 
 The ENGINE cells (``engine_rows``) run the full continuous-batching
-``ServeEngine`` under mixed-length traffic — paged KV cache vs contiguous,
-at VCI pool sizes 1/4/8 — and report end-to-end tok/s plus
-``cache_bytes_resident``: the paged pool is sized to the live-token budget
-(finished slots' pages reclaim immediately; admission allocates on entry),
-so it holds the SAME tokens in fewer resident bytes than the
-``batch x max_len`` contiguous cache.
+``ServeEngine`` under mixed-length traffic — a page pool sized to the
+live-token budget vs the default full pool, at VCI pool sizes 1/4/8 — and
+report end-to-end tok/s plus ``cache_bytes_resident``: finished slots'
+pages reclaim immediately and admission allocates on entry, so the sized
+pool holds the SAME tokens in fewer resident bytes than the full one
+(``1 + batch x max_len / page`` pages).
 """
 
 from __future__ import annotations
@@ -45,7 +45,7 @@ PROMPT = 16
 
 # engine (continuous-batching) cells: mixed-length traffic. max_len stays
 # at/below mixtral's sliding window so the MoE arch keeps a non-ring cache
-# (ring caches have no paged layout).
+# (ring caches have no paged layout and take the grouped fallback).
 ENGINE_MAX_LEN = 64
 ENGINE_BATCH = 4
 ENGINE_PAGE = 8
@@ -140,7 +140,7 @@ def run_cell(cfg, params, mesh, *, batch: int, lanes: int, num_vcis: int,
 
 def engine_requests(cfg, n: int, max_new: int):
     """Mixed-length traffic: prompt lengths in [8, 16] — the --vary-prompts
-    shape the left-padded/paged paths exist for."""
+    shape the left-padded continuous path exists for."""
     rng = np.random.default_rng(1)
     return [Request(prompt=rng.integers(0, cfg.vocab_size,
                                         (int(rng.integers(8, 17)),),
@@ -148,25 +148,25 @@ def engine_requests(cfg, n: int, max_new: int):
                     max_new_tokens=max_new) for _ in range(n)]
 
 
-def run_engine_cell(cfg, params, mesh, *, paged: bool, num_vcis: int,
+def run_engine_cell(cfg, params, mesh, *, sized: bool, num_vcis: int,
                     requests: int, max_new: int):
     """End-to-end continuous batching: #requests > batch_size so slots
-    recycle mid-stream (paged admission runs under the mesh)."""
+    recycle mid-stream (admission runs under the mesh)."""
     plan = ServeCommPlan(num_vcis=num_vcis, token_impl="data")
     eng = ServeEngine(cfg, params, batch_size=ENGINE_BATCH,
                       max_len=ENGINE_MAX_LEN, mesh=mesh, comm_plan=plan,
-                      paged=paged, page_size=ENGINE_PAGE,
-                      num_pages=ENGINE_PAGES if paged else None)
-    assert eng._paged == paged, "paged engine silently fell back"
+                      page_size=ENGINE_PAGE,
+                      num_pages=ENGINE_PAGES if sized else None)
+    assert eng._padded_ok, "the engine fell back to grouped batches"
     eng.generate(engine_requests(cfg, requests, max_new))  # compile warmup
     t = time_fn(lambda: eng.generate(engine_requests(cfg, requests, max_new)),
                 warmup=0, reps=2 if SMOKE else 3, min_time_s=0.0)
     n_tok = requests * max_new
     return {
-        "cache": "paged" if paged else "contiguous",
+        "pool": "sized" if sized else "full",
         "tok_s": n_tok / t["median_s"],
         "cache_bytes_resident": eng.cache_bytes_resident,
-        "admit_under_mesh": eng._can_admit,
+        "admit_under_mesh": bool(eng._admit_fns),
     }
 
 
@@ -206,7 +206,7 @@ def main():
         return next(r for r in rows if r["arch"] == arch
                     and r["batch"] == batch and r["num_vcis"] == nv)
 
-    # engine-level paged-vs-contiguous cells under mixed-length traffic
+    # engine-level sized-vs-full pool cells under mixed-length traffic
     eng_vcis = (1, 8) if SMOKE else (1, 4, 8)
     requests = 6 if SMOKE else 8
     max_new = 4 if SMOKE else 8
@@ -215,9 +215,9 @@ def main():
     for arch in archs:
         cfg = get_config(arch)
         params = init_params(cfg, jax.random.PRNGKey(0))
-        for paged in (False, True):
+        for sized in (False, True):
             for nv in eng_vcis:
-                r = run_engine_cell(cfg, params, mesh, paged=paged,
+                r = run_engine_cell(cfg, params, mesh, sized=sized,
                                     num_vcis=nv, requests=requests,
                                     max_new=max_new)
                 row = dict(arch=arch, num_vcis=nv,
@@ -227,9 +227,9 @@ def main():
                 eng_csv.add(**row)
     eng_csv.dump()
 
-    def eng_cell(arch, cache, nv):
+    def eng_cell(arch, pool, nv):
         return next(r for r in engine_rows if r["arch"] == arch
-                    and r["cache"] == cache and r["num_vcis"] == nv)
+                    and r["pool"] == pool and r["num_vcis"] == nv)
 
     # CPU-host wall clock is a PROXY (see benchmarks.common): tok/s cells
     # are reported per pool size, but the metric that transfers to the TPU
@@ -247,19 +247,19 @@ def main():
                 "depth_1vci": lo["critical_depth"],
                 "depth_maxvci": hi["critical_depth"],
             }
-    # the paged acceptance cell: same tokens, fewer resident cache bytes
+    # the sized-pool acceptance cell: same tokens, fewer resident bytes
     engine_summary = {}
     for arch in archs:
         for nv in eng_vcis:
-            c = eng_cell(arch, "contiguous", nv)
-            p = eng_cell(arch, "paged", nv)
+            f = eng_cell(arch, "full", nv)
+            p = eng_cell(arch, "sized", nv)
             engine_summary[f"{arch}/vcis{nv}"] = {
-                "tok_s_contiguous": c["tok_s"],
-                "tok_s_paged": p["tok_s"],
-                "cache_bytes_contiguous": c["cache_bytes_resident"],
-                "cache_bytes_paged": p["cache_bytes_resident"],
+                "tok_s_full": f["tok_s"],
+                "tok_s_sized": p["tok_s"],
+                "cache_bytes_full": f["cache_bytes_resident"],
+                "cache_bytes_sized": p["cache_bytes_resident"],
                 "cache_bytes_ratio": (p["cache_bytes_resident"]
-                                      / c["cache_bytes_resident"]),
+                                      / f["cache_bytes_resident"]),
             }
     emit_json("serve_streams", {"rows": rows, "engine_rows": engine_rows,
                                 "summary": summary,

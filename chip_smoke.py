@@ -7,14 +7,11 @@
 One chip:
 
 * serve: olmo-1b at its published widths and full depth through
-  ``ServeEngine.generate``, with the paged and the contiguous KV cache, batch
-  4 and 8 requests so that finished slots take new requests mid-stream. The
-  paged decode step must hold the Pallas paged attention kernel
-  (``tpu_custom_call``);
-  a full forward over each finished sequence must be finite and pick the
-  same tokens wherever its top-2 margin is wider than ``LOGIT_BOUND``; the
-  two layouts' tokens must be identical up to the first position whose
-  margin is within it (the programs sum in different orders on a TPU);
+  ``ServeEngine.generate`` on the paged KV cache, batch 4 and 8 requests so
+  that finished slots take new requests mid-stream. The decode step must
+  hold the Pallas paged attention kernel (``tpu_custom_call``); a full
+  forward over each finished sequence must be finite and pick the same
+  tokens wherever its top-2 margin is wider than ``LOGIT_BOUND``;
 * train: olmo-1b at published widths cut to ``TRAIN_LAYERS`` layers, a few
   steps through ``make_train_step`` with ``comm="vci"`` (fused Pallas bucket
   pack/unpack, barrier ordering tokens) and then ``comm="gspmd"`` on the same
@@ -59,8 +56,6 @@ SERVE_BATCH = 4
 PROMPT_LENS = (128, 64, 128, 64, 64, 128, 64, 128)
 MAX_NEW = 32
 PAGE_SIZE = 16
-# A multiple of PAGE_SIZE, so the paged view and the contiguous cache attend
-# over the same width.
 MAX_LEN = 256
 TRAIN_LAYERS = 4
 TRAIN_BATCH = 8
@@ -249,45 +244,32 @@ def serve_one_chip(cfg, clock: CompileClock) -> None:
         f"{MAX_NEW} new tokens each")
     params = init_params(cfg, jax.random.PRNGKey(SEED))
     reqs = make_requests(cfg)
-    toks = {}
-    for paged in (True, False):
-        label = "serve paged" if paged else "serve contiguous"
-        engine = ServeEngine(cfg, params, batch_size=SERVE_BATCH,
-                             max_len=MAX_LEN, paged=paged,
-                             page_size=PAGE_SIZE)
-        toks[paged] = generate_twice(engine, reqs, clock, label)
-        if paged:
-            check(len(engine._admit_fns) > 0,
-                  "serve paged: requests were admitted mid-stream")
-            b = SERVE_BATCH
-            cache = jax.eval_shape(lambda: init_paged_cache(
-                cfg, b, MAX_LEN, page_size=PAGE_SIZE,
-                num_pages=engine._num_pages, dtype=engine._cache_dtype))
-            i32 = jax.ShapeDtypeStruct((b,), jnp.int32)
-            hlo = engine._step.lower(
-                params, jax.ShapeDtypeStruct((b, 1), jnp.int32), cache, i32,
-                jax.ShapeDtypeStruct((b,), jnp.float32),
-                jax.random.PRNGKey(0)).compile().as_text()
-            n = hlo.count("tpu_custom_call")
-            say(f"[serve paged] decode step: {n} tpu_custom_call mentions")
-            check(n > 0, "serve paged: the decode step runs the Pallas paged "
-                         "attention kernel")
-        report_memory(label)
-    # One full forward over both layouts' sequences.
+    engine = ServeEngine(cfg, params, batch_size=SERVE_BATCH,
+                         max_len=MAX_LEN, page_size=PAGE_SIZE)
+    toks = generate_twice(engine, reqs, clock, "serve")
+    check(len(engine._admit_fns) > 0,
+          "serve: requests were admitted mid-stream")
+    b = SERVE_BATCH
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        cfg, b, MAX_LEN, page_size=PAGE_SIZE,
+        num_pages=engine._num_pages, dtype=engine._cache_dtype))
+    i32 = jax.ShapeDtypeStruct((b,), jnp.int32)
+    hlo = engine._step.lower(
+        params, jax.ShapeDtypeStruct((b, 1), jnp.int32), cache, i32,
+        jax.ShapeDtypeStruct((b,), jnp.float32),
+        jax.random.PRNGKey(0)).compile().as_text()
+    n = hlo.count("tpu_custom_call")
+    say(f"[serve] decode step: {n} tpu_custom_call mentions")
+    check(n > 0, "serve: the decode step runs the Pallas paged attention "
+                 "kernel")
+    report_memory("serve")
     c0 = clock.secs
-    logits, start, picks = teacher_forced(cfg, params, reqs + reqs,
-                                          toks[True] + toks[False])
+    logits, start, picks = teacher_forced(cfg, params, reqs, toks)
     live = np.arange(logits.shape[1])[None, :] >= start[:, None]
     check(bool(np.isfinite(logits[live]).all()),
           "serve: every logit of the full forward is finite")
     say(f"[serve forward] compile_s={clock.secs - c0:.2f}")
-    n = len(reqs)
-    check_against_forward("serve paged", picks[:n], toks[True])
-    check_against_forward("serve contiguous", picks[n:], toks[False])
-    # Not bitwise: the paged kernel's online softmax and the contiguous
-    # cache's attention run the q.k and softmax sums in another order.
-    check_same_greedy("serve paged vs contiguous", picks[n:], toks[False],
-                      toks[True])
+    check_against_forward("serve", picks, toks)
 
 
 def serve_four_chips(cfg, clock: CompileClock) -> None:
@@ -337,10 +319,10 @@ def serve_four_chips(cfg, clock: CompileClock) -> None:
                               f"{LOGIT_BOUND} of one device")
 
     one = ServeEngine(cfg, params, batch_size=SERVE_BATCH, max_len=MAX_LEN,
-                      paged=True, page_size=PAGE_SIZE)
+                      page_size=PAGE_SIZE)
     ref_toks = generate_twice(one, reqs, clock, "serve one device")
     eng = ServeEngine(cfg, params_tp, batch_size=SERVE_BATCH,
-                      max_len=MAX_LEN, mesh=mesh, comm_plan=plan, paged=True,
+                      max_len=MAX_LEN, mesh=mesh, comm_plan=plan,
                       page_size=PAGE_SIZE)
     tp_toks = generate_twice(eng, reqs, clock, "serve tp")
     check(len(eng._admit_fns) > 0,

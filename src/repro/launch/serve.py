@@ -9,12 +9,12 @@ Serve-path VCI streams (manual TP, collectives on per-purpose CommContexts):
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b-smoke \
         --tp 2 --num-vcis 8 --policy fcfs --temperature 0.8 --stop 17
 
-Paged KV cache (pool of fixed-size pages + per-slot page table; mid-stream
-admission then also works under the mesh):
+Mixed prompt lengths on a right-sized page pool (pool of fixed-size pages +
+per-slot page table; slots recycle mid-stream, under the mesh too):
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b-smoke \
-        --tp 2 --vary-prompts --paged --page-size 16 --pages 40
+        --tp 2 --vary-prompts --page-size 16 --pages 40
 """
 
 from __future__ import annotations
@@ -55,11 +55,8 @@ def main() -> None:
     ap.add_argument("--policy", default="fcfs",
                     choices=("fcfs", "round_robin", "hash", "hinted"),
                     help="VCI pool assignment policy (tp>1)")
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV cache (page pool + per-slot page table); "
-                         "mid-stream admission then works under --tp too")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="tokens per page (paged cache)")
+                    help="tokens per page of the paged cache")
     ap.add_argument("--pages", type=int, default=None,
                     help="page-pool size incl. the trash page (default: "
                          "full provision batch*ceil(max_len/page_size)+1)")
@@ -88,18 +85,11 @@ def main() -> None:
     engine = ServeEngine(cfg, params, batch_size=args.batch,
                          max_len=args.max_len, mesh=mesh,
                          comm_plan=comm_plan, temperature=args.temperature,
-                         seed=args.seed, paged=args.paged,
-                         page_size=args.page_size, num_pages=args.pages)
-    if args.paged:
-        if not engine._paged:
-            raise SystemExit(
-                f"--paged requested but arch {cfg.name!r} has no paged "
-                f"layout (ring/SSM/audio/VLM caches fall back to grouped "
-                f"contiguous batches) — drop --paged or pick an attention "
-                f"arch with max_len <= its sliding window")
+                         seed=args.seed, page_size=args.page_size,
+                         num_pages=args.pages)
+    if engine._padded_ok:
         print(f"paged cache: page_size={args.page_size} "
-              f"num_pages={engine._num_pages} "
-              f"(admit_under_mesh={engine._can_admit})")
+              f"num_pages={engine._num_pages}")
 
     rng = np.random.default_rng(args.seed)
     reqs = []

@@ -315,8 +315,7 @@ def paged_prefill_update(layer: PagedKVLayer, k_new, v_new) -> PagedKVLayer:
 def paged_splice(cache: PagedKVCache, slot, dest, k_rows, v_rows
                  ) -> PagedKVCache:
     """Admission splice: write ``k_rows``/``v_rows`` (``(L, S, KV, hd)``)
-    into ``slot``'s pages at virtual positions ``[dest, dest + S)`` — the
-    paged analogue of the contiguous engine's dynamic_update_slice splice,
+    into ``slot``'s pages at virtual positions ``[dest, dest + S)``,
     page-table-indirect and not page-aligned (positions below the admitted
     request's ``start`` fall through unmapped entries to the trash page)."""
     ps = cache.page_size

@@ -265,6 +265,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                        jnp.zeros((), jnp.int32))
 
 
+def has_paged_layout(cfg: ModelConfig, max_len: int) -> bool:
+    """Whether ``cfg`` has a paged decode cache at depth ``max_len``: a text
+    model of one mixer per layer (dense, MoE, SSM and the per-layer pattern
+    hybrids) with no ring cache. A ring (sliding window under ``max_len``)
+    reuses slots modulo the window, zamba2's shared attention block has no
+    per-layer stack, and the VLM/audio frontends take no per-row starts."""
+    return (cfg.mixers is not None and cfg.modality == "text"
+            and (cfg.sliding_window is None
+                 or cfg.sliding_window >= max_len))
+
+
 def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
                      page_size: int, num_pages: int,
                      dtype=jnp.bfloat16) -> DecodeCache:
@@ -274,22 +285,13 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     ``[0, max_len)``, and beside it a per-slot recurrent state for each
     Mamba-2 layer (the state has no positions, so no pages). A page holds
     ``(page_size, KV, hd)``, or ``(page_size, KV*hd)`` for heads narrower
-    than 128.
-
-    Text models of one mixer per layer only (dense, MoE, SSM and the
-    per-layer pattern hybrids): ring (sliding-window) caches reuse slots
-    modulo the window, and zamba2's shared attention block has no per-layer
-    stack — the serve engine keeps the grouped contiguous fallback for
-    those.
+    than 128. Only for configs :func:`has_paged_layout` accepts.
     """
-    if cfg.mixers is None or cfg.modality != "text":
+    if not has_paged_layout(cfg, max_len):
         raise NotImplementedError(
-            f"paged cache needs a text model of one mixer per layer, got "
-            f"family={cfg.family!r} modality={cfg.modality!r}")
-    if cfg.sliding_window is not None and cfg.sliding_window < max_len:
-        raise NotImplementedError(
-            "paged KV cache does not support ring (sliding-window) caches; "
-            "use the contiguous cache")
+            f"no paged layout for family={cfg.family!r} modality="
+            f"{cfg.modality!r} sliding_window={cfg.sliding_window} at "
+            f"max_len={max_len}; init_cache holds its contiguous cache")
     if page_size < 1 or num_pages < 2:
         raise ValueError(f"need page_size >= 1 and num_pages >= 2 "
                          f"(page 0 is the trash page), got "

@@ -8,34 +8,32 @@ partial sums, MoE combine, vocab-parallel sampling gather) each ride their
 own CommContext/VCI stream — the serve-side analogue of the gradient
 bucketing path.
 
-``ServeEngine`` is the host-side continuous-batching loop:
+``ServeEngine`` is the host-side continuous-batching loop over the PAGED
+decode cache (:class:`~repro.models.attention.PagedKVCache` + the pure-JAX
+allocator in :mod:`repro.serve.paging`):
 
 * mixed-length prompts are LEFT-padded to a common width and prefilled with
   per-row pad masks + shifted RoPE positions, so a request's tokens are
-  identical no matter what it is batched with (the old engine truncated the
-  batch to the shortest prompt);
+  identical no matter what it is batched with;
 * greedy or per-request temperature sampling, per-request ``stop_token``
   and ``max_new_tokens``;
-* early slot recycling: a finished slot is re-filled mid-stream by
-  prefilling the next request's prompt into the cache rows just below the
-  shared write cursor (its ``start`` offset masks everything older);
-* ``generate()`` validates ``prompt_len + max_new_tokens <= max_len`` up
-  front — decode can never write past the cache depth.
+* a finished slot's pages return to the pool the moment it finishes, and
+  the slot is re-filled mid-stream by prefilling the next request into
+  freshly allocated pages just below the shared write cursor (its ``start``
+  offset masks everything older) — on one device or under a mesh, with the
+  running batch's TP specs;
+* ``generate()`` validates ``prompt_len + max_new_tokens <= max_len`` and
+  each request's page span against the pool up front — decode can never
+  write past the cache depth or run out of pages.
 
-``paged=True`` replaces the contiguous cache with the PAGED KV cache
-(:class:`~repro.models.attention.PagedKVCache` + the pure-JAX allocator in
-:mod:`repro.serve.paging`): a finished slot's pages are reclaimed the
-moment it finishes, and mid-stream admission works under a mesh because
-the admitted request prefills into freshly allocated pages under the same
-TP specs as the running batch. See the :class:`ServeEngine` docstring.
-
-Text models of one mixer per layer take the continuous path: dense, MoE,
-SSM and the per-layer pattern hybrids (granite-4.0-h), whose Mamba-2 layers
-keep a recurrent state per slot beside the attention layers' K/V. Left-pad
+Text models of one mixer per layer with no ring cache have a paged layout
+(:func:`~repro.models.transformer.has_paged_layout`): dense, MoE, SSM and
+the per-layer pattern hybrids (granite-4.0-h), whose Mamba-2 layers keep a
+recurrent state per slot beside the attention layers' pages. Left-pad
 positions give a Mamba-2 layer no step, and admission writes the slot's
 state row. Ring caches, zamba2's shared attention block and the VLM/audio
-frontends fall back to equal-length grouped batches — same results, no
-corruption, just less packing.
+frontends have none and fall back to equal-length grouped batches on the
+contiguous cache — same results, no corruption, just less packing.
 """
 
 from __future__ import annotations
@@ -57,6 +55,7 @@ from repro.models.ssm import SSMState
 from repro.models.transformer import (
     DecodeCache,
     Model,
+    has_paged_layout,
     init_cache,
     init_paged_cache,
 )
@@ -282,26 +281,19 @@ class ServeEngine:
     whose collectives ride per-purpose VCI streams; with ``mesh=None`` the
     same loop runs single-device.
 
-    ``paged=True`` swaps the contiguous left-padded cache for the paged KV
-    cache: a fixed pool of ``num_pages`` pages of ``page_size`` tokens plus
-    a per-slot page table (:class:`~repro.models.attention.PagedKVCache`,
-    allocation in :mod:`repro.serve.paging`). Two limits of the contiguous
-    layout fall away:
-
-    * a finished slot's pages return to the pool IMMEDIATELY (per-slot
-      compaction for free), so ``num_pages`` can be sized to the live-token
-      budget instead of ``batch * max_len`` — lower resident cache bytes at
-      equal tokens;
-    * mid-stream admission works under a mesh: the admitted request
-      prefills into freshly allocated pages via the SAME mesh/TP specs as
-      the running batch (the contiguous engine can only splice-admit
-      single-device).
-
+    The continuous path keeps its decode state in a fixed pool of
+    ``num_pages`` pages of ``page_size`` tokens plus a per-slot page table
+    (:class:`~repro.models.attention.PagedKVCache`, allocation in
+    :mod:`repro.serve.paging`). A finished slot's pages return to the pool
+    IMMEDIATELY, so ``num_pages`` can be sized to the live-token budget
+    instead of the default ``1 + batch * ceil(max_len / page_size)``.
     The pages hold the attention layers' K/V; a Mamba-2 layer's state has
     no positions and lives beside them, one row per slot, in the same
-    :class:`~repro.models.transformer.DecodeCache`. Ring (sliding-window)
-    caches, zamba2's shared attention block and audio/VLM frontends have no
-    paged layout; those keep the grouped equal-length contiguous fallback.
+    :class:`~repro.models.transformer.DecodeCache`. Models without a paged
+    layout take the grouped equal-length fallback on their own.
+
+    ``paged`` is an input check only: ``paged=False`` (the removed
+    contiguous continuous cache) raises ``ValueError``.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, batch_size: int,
@@ -310,8 +302,13 @@ class ServeEngine:
                  num_vcis: Optional[int] = None, vci_policy: str = "fcfs",
                  progress: str = "hybrid", token_impl: str = "barrier",
                  temperature: float = 0.0, seed: int = 0,
-                 paged: bool = False, page_size: int = 16,
+                 paged: bool = True, page_size: int = 16,
                  num_pages: Optional[int] = None):
+        if not paged:
+            raise ValueError(
+                "paged=False: the contiguous continuous cache was removed; "
+                "models without a paged layout take the grouped path on "
+                "their own")
         self.cfg = cfg
         self.params = params
         self.batch_size = batch_size
@@ -337,30 +334,19 @@ class ServeEngine:
         self._cache_dtype = cache_dtype
         self._key = jax.random.PRNGKey(seed)
         self._nkey = 0
-        self._ring = (cfg.sliding_window is not None
-                      and cfg.sliding_window < max_len)
-        # left-padded mixed-length batching needs every layer's state to
-        # take per-row starts: attention masks, Mamba-2 steps skipped. Ring
-        # caches, zamba2's shared block and non-text frontends can't ->
-        # equal-length grouped batches for those.
-        self._padded_ok = (cfg.mixers is not None and cfg.modality == "text"
-                           and not self._ring)
-        # paged cache: the continuous path only; the grouped fallback keeps
-        # the contiguous cache.
-        self._paged = bool(paged) and self._padded_ok
+        # continuous batching on the page pool, or the grouped fallback
+        self._padded_ok = has_paged_layout(cfg, max_len)
         self._page_size = int(page_size)
         self._max_pages = -(-max_len // self._page_size)
         self._num_pages = (1 + batch_size * self._max_pages
                            if num_pages is None else int(num_pages))
-        if self._paged and self._num_pages < 2:
+        if self._padded_ok and self._num_pages < 2:
             raise ValueError(f"num_pages must be >= 2 (page 0 is the trash "
                              f"page), got {self._num_pages}")
-        # mid-stream admission re-prefills single requests. The contiguous
-        # splice is single-device only (B=1 doesn't shard over the data
-        # axes); the PAGED admission prefill runs replicated over data under
-        # the running batch's TP specs, so it works on any mesh.
-        self._can_admit = mesh is None or self._paged
         self.cache_bytes_resident = 0
+
+    # read by chipbench/test_chipbench_hybrid.py
+    _paged = property(lambda self: self._padded_ok)
 
     # -- small helpers ---------------------------------------------------
     def _next_key(self):
@@ -383,7 +369,7 @@ class ServeEngine:
                     f"{r.max_new_tokens} exceeds the cache depth "
                     f"(max_len={self.max_len}); decode would write past the "
                     f"cache — shorten the request or raise max_len")
-            if self._paged:
+            if self._padded_ok:
                 need = pages_for_span(0, plen + r.max_new_tokens,
                                       self._page_size)
                 if need > self._num_pages - 1:
@@ -395,7 +381,7 @@ class ServeEngine:
 
     def _note_cache(self, cache: DecodeCache) -> None:
         """Track the largest resident decode-cache footprint of this
-        ``generate()`` call — the paged-vs-contiguous benchmark metric."""
+        ``generate()`` call — what a right-sized page pool saves."""
         n = 0
         for leaf in jax.tree_util.tree_leaves(cache):
             n += leaf.size * leaf.dtype.itemsize
@@ -429,10 +415,10 @@ class ServeEngine:
     def _take_batch(self, pending: List[Request]) -> List[Request]:
         """Pop up to ``batch_size`` requests whose LEFT-PADDED runway fits:
         with pad width P = max(prompt lens), every member still needs
-        ``P + max_new <= max_len`` (padding consumes cache depth). Paged:
-        additionally, the members' worst-case page spans (prompt + full
-        token budget, page-rounded — the reservation that keeps allocation
-        infallible) must fit the pool together."""
+        ``P + max_new <= max_len`` (padding consumes cache depth), and the
+        members' worst-case page spans (prompt + full token budget,
+        page-rounded — the reservation that keeps allocation infallible)
+        must fit the pool together."""
         batch: List[Request] = []
         pad = 0
         i = 0
@@ -440,14 +426,12 @@ class ServeEngine:
             r = pending[i]
             p_new = max(pad, int(r.prompt.shape[-1]))
             members = batch + [r]
-            fits = all(p_new + q.max_new_tokens <= self.max_len
-                       for q in members)
-            if fits and self._paged:
-                fits = sum(
-                    pages_for_span(p_new - int(q.prompt.shape[-1]),
-                                   p_new + q.max_new_tokens,
-                                   self._page_size)
-                    for q in members) <= self._num_pages - 1
+            fits = (all(p_new + q.max_new_tokens <= self.max_len
+                        for q in members)
+                    and sum(pages_for_span(p_new - int(q.prompt.shape[-1]),
+                                           p_new + q.max_new_tokens,
+                                           self._page_size)
+                            for q in members) <= self._num_pages - 1)
             if fits:
                 batch.append(pending.pop(i))
                 pad = p_new
@@ -481,22 +465,18 @@ class ServeEngine:
                             for s in slots], np.float32)
         reserved: Dict[int, int] = {}  # slot -> worst-case page span
         with TraceAnnotation("serve.pool_init"):
-            if self._paged:
-                cache = init_paged_cache(cfg, B, self.max_len, page_size=PS,
-                                         num_pages=self._num_pages,
-                                         dtype=self._cache_dtype)
-                self._owner = page_state_init(self._num_pages, B,
-                                              self._max_pages).owner
-                for i, s in enumerate(slots):
-                    if s.req is None:
-                        continue  # empty slot: writes land in the trash page
-                    cache = self._palloc(cache, i, int(start[i]) // PS,
-                                         (pad - 1) // PS)
-                    reserved[i] = pages_for_span(
-                        int(start[i]), pad + s.req.max_new_tokens, PS)
-            else:
-                cache = init_cache(cfg, B, self.max_len,
-                                   dtype=self._cache_dtype)
+            cache = init_paged_cache(cfg, B, self.max_len, page_size=PS,
+                                     num_pages=self._num_pages,
+                                     dtype=self._cache_dtype)
+            self._owner = page_state_init(self._num_pages, B,
+                                          self._max_pages).owner
+            for i, s in enumerate(slots):
+                if s.req is None:
+                    continue  # empty slot: writes land in the trash page
+                cache = self._palloc(cache, i, int(start[i]) // PS,
+                                     (pad - 1) // PS)
+                reserved[i] = pages_for_span(
+                    int(start[i]), pad + s.req.max_new_tokens, PS)
             self._note_cache(cache)
         with TraceAnnotation("serve.prefill"):
             nxt, cache = self._prefill(
@@ -516,7 +496,7 @@ class ServeEngine:
             """Per-slot compaction for free: the instant a slot finishes its
             pages go back to the pool (its decode writes re-route to the
             trash page through the cleared table row)."""
-            if not (self._paged and s.done and i in reserved):
+            if not (s.done and i in reserved):
                 return cache
             st = free_slot_pages_jit(
                 PageState(cache.kv.table, self._owner),
@@ -536,29 +516,27 @@ class ServeEngine:
                         cache = reclaim(i, s, cache)
             # early slot recycling: prefill the next request into a finished
             # slot just below the shared cursor (start masks older rows)
-            if self._can_admit and pending:
-                for i, s in enumerate(slots):
-                    if not s.done or not pending:
-                        continue
-                    j = self._admittable(pending, cur, reserved)
-                    if j is None:
-                        continue
-                    r = pending.pop(j)
-                    plen = int(r.prompt.shape[-1])
-                    with TraceAnnotation("serve.admit"):
-                        if self._paged:
-                            cache = self._palloc(cache, i, (cur - plen) // PS,
-                                                 (cur - 1) // PS)
-                            reserved[i] = pages_for_span(
-                                cur - plen, cur + r.max_new_tokens, PS)
-                        tok0, cache = self._admit(r, cache, i, cur)
-                        s.activate(r)
-                        start[i] = cur - plen
-                        temps[i] = self._temp_of(r)
-                        toks[i, 0] = tok0
-                        record(s, tok0)  # the admission prefill's first token
-                        cache = reclaim(i, s, cache)
-                    admitted = True
+            for i, s in enumerate(slots):
+                if not s.done or not pending:
+                    continue
+                j = self._admittable(pending, cur, reserved)
+                if j is None:
+                    continue
+                r = pending.pop(j)
+                plen = int(r.prompt.shape[-1])
+                with TraceAnnotation("serve.admit"):
+                    cache = self._palloc(cache, i, (cur - plen) // PS,
+                                         (cur - 1) // PS)
+                    reserved[i] = pages_for_span(
+                        cur - plen, cur + r.max_new_tokens, PS)
+                    tok0, cache = self._admit(r, cache, i, cur)
+                    s.activate(r)
+                    start[i] = cur - plen
+                    temps[i] = self._temp_of(r)
+                    toks[i, 0] = tok0
+                    record(s, tok0)  # the admission prefill's first token
+                    cache = reclaim(i, s, cache)
+                admitted = True
             if all(s.done or s.req is None for s in slots):
                 break
             if cur >= self.max_len:  # defensive: budgets guarantee this
@@ -566,7 +544,7 @@ class ServeEngine:
                     if not s.done:
                         s.finish()
                 break
-            if self._paged and cur % PS == 0:
+            if cur % PS == 0:
                 # the shared cursor crosses into a fresh logical page: every
                 # live slot gets one (reservation makes this infallible)
                 act = [i for i, s in enumerate(slots) if not s.done]
@@ -593,25 +571,23 @@ class ServeEngine:
             cur += 1
 
     def _admittable(self, pending: List[Request], cur: int,
-                    reserved: Optional[Dict[int, int]] = None
-                    ) -> Optional[int]:
+                    reserved: Dict[int, int]) -> Optional[int]:
         """Index of the first pending request that fits at cursor ``cur``:
-        its prompt must fit below the cursor and its token budget inside the
-        remaining cache depth — and, paged, its worst-case page span must
-        fit next to the live slots' reservations."""
+        its prompt must fit below the cursor, its token budget inside the
+        remaining cache depth, and its worst-case page span next to the
+        live slots' reservations."""
         for j, r in enumerate(pending):
             plen = int(r.prompt.shape[-1])
             if plen > cur or cur + r.max_new_tokens > self.max_len:
                 continue
-            if self._paged:
-                need = pages_for_span(cur - plen, cur + r.max_new_tokens,
-                                      self._page_size)
-                if sum(reserved.values()) + need > self._num_pages - 1:
-                    continue
+            need = pages_for_span(cur - plen, cur + r.max_new_tokens,
+                                  self._page_size)
+            if sum(reserved.values()) + need > self._num_pages - 1:
+                continue
             return j
         return None
 
-    # -- page-pool bookkeeping (paged mode) --------------------------------
+    # -- page-pool bookkeeping ----------------------------------------------
     def _with_table(self, cache: DecodeCache, table) -> DecodeCache:
         kv = cache.kv
         return DecodeCache(
@@ -635,14 +611,11 @@ class ServeEngine:
             return self._with_table(cache, st.table)
 
     def _admit(self, r: Request, cache, slot: int, cur: int):
-        """Prefill ``r`` alone and splice its KV rows into ``slot``'s cache
-        at virtual positions ``[cur - plen, cur)``, and its Mamba-2 state
-        into ``slot``'s state row; returns (first token, cache).
-        Contiguous: a dynamic_update_slice into the slot's row,
-        single-device only. Paged: a page-table splice into the slot's
-        freshly allocated pages — under a mesh the prefill runs replicated
-        over the data axes with the running batch's TP specs, the
-        shard-aware admission the contiguous splice can't do."""
+        """Prefill ``r`` alone and splice its KV rows into ``slot``'s
+        freshly allocated pages at virtual positions ``[cur - plen, cur)``,
+        and its Mamba-2 state into ``slot``'s state row; returns (first
+        token, cache). Under a mesh the prefill runs replicated over the
+        data axes with the running batch's TP specs."""
         plen = int(r.prompt.shape[-1])
         p_adm = min(-(-plen // _ADMIT_ALIGN) * _ADMIT_ALIGN, cur)
         fn = self._admit_fn(p_adm)
@@ -661,17 +634,15 @@ class ServeEngine:
     def _admit_fn(self, p_adm: int):
         """Jitted single-request admission prefill, cached per padded
         prompt width (widths are rounded to ``_ADMIT_ALIGN`` to bound the
-        number of traces). The cache write is the only layout-specific
-        part: contiguous DUS splice vs page-table splice."""
+        number of traces)."""
         fn = self._admit_fns.get(p_adm)
         if fn is not None:
             return fn
         if self.comm_plan is not None:
-            fn = self._build_admit_comm(p_adm)  # paged-only (_can_admit)
+            fn = self._build_admit_comm(p_adm)
         else:
             cfg = self.cfg
             model = Model(cfg)
-            paged = self._paged
 
             def admit(params, tokens, cache, slot, dest, start1, temp1, key):
                 tmp = init_cache(cfg, 1, tokens.shape[1],
@@ -680,19 +651,9 @@ class ServeEngine:
                                                cache=tmp, start=start1)
                 nxt = select_tokens(_last_logits(cfg, logits), temp1, key)
                 kv = cache.kv
-                if tmp.kv is None:  # no attention layers
-                    pass
-                elif paged:
+                if tmp.kv is not None:  # None: no attention layers
                     kv = paged_splice(kv, slot, dest,
                                       tmp.kv.k[:, 0], tmp.kv.v[:, 0])
-                else:
-                    k = jax.lax.dynamic_update_slice(
-                        kv.k, tmp.kv.k.astype(kv.k.dtype),
-                        (0, slot, dest, 0, 0))
-                    v = jax.lax.dynamic_update_slice(
-                        kv.v, tmp.kv.v.astype(kv.v.dtype),
-                        (0, slot, dest, 0, 0))
-                    kv = KVCache(k, v, kv.length, kv.ring)
                 ssm = cache.ssm
                 if ssm is not None:  # the slot's Mamba-2 state row
                     ssm = SSMState(

@@ -19,9 +19,9 @@ Pool page 0 is the TRASH page (``owner[0] = OWNER_RESERVED``): finished
 slots' decode writes and pad-prefix prefill writes land there, so the model
 code never needs a branch for "this row has no page" — attention masks the
 positions anyway. Allocation picks the LOWEST free pool ids (``jnp.nonzero``
-order), which keeps the realized mapping deterministic: paged and contiguous
-engines must produce identical tokens, so nothing downstream may depend on
-*which* page a slot got, and the tests pin that determinism.
+order), which keeps the realized mapping deterministic: a request's tokens
+must not depend on *which* pages its slot got, and the tests pin that
+determinism.
 
 Capacity is the CALLER's contract: the engine reserves worst-case page
 spans at batch formation / admission time, so ``alloc`` never runs out.

@@ -414,12 +414,10 @@ def check_serve_streams_match_single_stream():
     fallback stream) and num_vcis=8 (dedicated streams). Mixed-length
     batches ride along so left-padded prefill is exercised under TP too.
 
-    The PAGED cells repeat the sweep with the paged KV cache and
-    batch_size=2 < #requests, so mid-stream admission (page alloc + the
-    shard-aware admission prefill + splice) runs UNDER the mesh — the
-    continuous-batching limit this cache lifts — and still with identical
-    tokens; the paged pool must also hold fewer resident bytes than the
-    full-provision contiguous cache despite the extra page table."""
+    The mesh engine has batch_size=2 < #requests, so mid-stream admission
+    (page alloc + the shard-aware admission prefill + splice) runs UNDER
+    the mesh, and a right-sized pool must hold fewer resident bytes than
+    the single-device engine's full pool despite the extra page table."""
     from repro.configs import get_config
     from repro.models.transformer import init_params
     from repro.serve.comm import PURPOSES, ServeCommPlan
@@ -445,10 +443,12 @@ def check_serve_streams_match_single_stream():
 
         for num_vcis in (1, 8):
             plan = ServeCommPlan(num_vcis=num_vcis, token_impl="data")
-            eng = ServeEngine(cfg, params, batch_size=4, max_len=48,
-                              mesh=mesh, comm_plan=plan)
+            eng = ServeEngine(cfg, params, batch_size=2, max_len=48,
+                              mesh=mesh, comm_plan=plan, page_size=8,
+                              num_pages=11)
             got = make_requests()
             eng.generate(got)
+            assert eng._admit_fns, "no request was admitted under the mesh"
             for i, (a, b) in enumerate(zip(got, ref)):
                 np.testing.assert_array_equal(
                     a.generated, b.generated,
@@ -462,25 +462,10 @@ def check_serve_streams_match_single_stream():
             else:
                 assert len(indices) == len(PURPOSES), plan.vci_map()
                 assert plan.stats.fallback_hits == 0
-
-            # paged cells: same tokens through page-table indirection, with
-            # mid-stream admission exercised under the mesh
-            plan_p = ServeCommPlan(num_vcis=num_vcis, token_impl="data")
-            eng_p = ServeEngine(cfg, params, batch_size=2, max_len=48,
-                                mesh=mesh, comm_plan=plan_p, paged=True,
-                                page_size=8, num_pages=11)
-            assert eng_p._paged and eng_p._can_admit, \
-                "paged engine must admit mid-stream under the mesh"
-            got_p = make_requests()
-            eng_p.generate(got_p)
-            for i, (a, b) in enumerate(zip(got_p, ref)):
-                np.testing.assert_array_equal(
-                    a.generated, b.generated,
-                    err_msg=f"{arch} paged num_vcis={num_vcis} request {i}")
-            owner = np.asarray(eng_p._owner)
+            owner = np.asarray(eng._owner)
             assert (owner[1:] == -1).all(), f"pages leaked: {owner}"
-            assert eng_p.cache_bytes_resident < solo.cache_bytes_resident, (
-                eng_p.cache_bytes_resident, solo.cache_bytes_resident)
+            assert eng.cache_bytes_resident < solo.cache_bytes_resident, (
+                eng.cache_bytes_resident, solo.cache_bytes_resident)
 
 
 def check_vci_trainer_lowers_production_mesh():

@@ -115,19 +115,18 @@ def test_serve_streams_smoke(tmp_path):
         # the collective critical path vs the single fallback stream
         assert s["depth_maxvci"] < s["depth_1vci"], (arch, s)
         assert s["tok_s_1vci"] > 0 and s["tok_s_maxvci"] > 0
-    # paged-vs-contiguous engine cells: both archs, both layouts, paged
+    # sized-vs-full page pool engine cells: both archs, both pools,
     # admission under the mesh, and fewer resident cache bytes at equal
-    # tokens (the paged acceptance claim)
-    eng_cells = {(r["arch"], r["cache"]) for r in doc["engine_rows"]}
+    # tokens in the sized pool (the paging acceptance claim)
+    eng_cells = {(r["arch"], r["pool"]) for r in doc["engine_rows"]}
     for arch in ("olmo-1b-smoke", "mixtral-8x22b-smoke"):
-        assert (arch, "paged") in eng_cells
-        assert (arch, "contiguous") in eng_cells
+        assert (arch, "sized") in eng_cells
+        assert (arch, "full") in eng_cells
     for r in doc["engine_rows"]:
-        if r["cache"] == "paged":
-            assert r["admit_under_mesh"], r
+        assert r["admit_under_mesh"], r
     for key, s in doc["engine_summary"].items():
         assert s["cache_bytes_ratio"] < 1.0, (key, s)
-        assert s["tok_s_paged"] > 0 and s["tok_s_contiguous"] > 0
+        assert s["tok_s_sized"] > 0 and s["tok_s_full"] > 0
 
 
 @pytest.mark.slow

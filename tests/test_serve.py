@@ -1,5 +1,7 @@
 """Serving-engine tests."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,38 +80,89 @@ def test_select_tokens_mixes_greedy_and_sampled_rows():
     assert ((toks >= 0) & (toks < 16)).all()
 
 
+@functools.lru_cache(maxsize=None)
+def _setup(arch):
+    cfg = get_config(arch)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
 @pytest.fixture(scope="module")
 def olmo_setup():
-    cfg = get_config("olmo-1b-smoke")
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    return cfg, params
+    return _setup("olmo-1b-smoke")
 
 
-def _solo_tokens(cfg, params, req: Request, max_len=64):
-    """Reference: the request decoded alone in a batch of one."""
-    eng = ServeEngine(cfg, params, batch_size=1, max_len=max_len)
-    ref = Request(prompt=req.prompt.copy(),
-                  max_new_tokens=req.max_new_tokens,
-                  stop_token=req.stop_token)
-    eng.generate([ref])
-    return ref.generated
+# Top-2 logit margin within which two programs that sum in different
+# orders (a cache against a full forward) may pick either token.
+NEAR_TIE = 1e-3
+_REF_WIDTH = 64
 
 
-def test_mixed_length_batch_matches_solo(olmo_setup):
+@functools.lru_cache(maxsize=None)
+def _last_logits_fn(cfg):
+    model = Model(cfg)
+    return jax.jit(lambda p, t, s: model.forward(
+        p, {"tokens": t}, start=s)[0][0, -1])
+
+
+def _assert_matches_forward(cfg, params, req: Request, what: str = ""):
+    """Reference with no cache: greedy decoding by a full ``Model.forward``
+    over the prompt plus the tokens picked so far (left-padded to one width,
+    so one compile). ``req.generated`` must equal it, stop token included,
+    up to the first position whose top-2 margin is within ``NEAR_TIE``;
+    past such a position the two sequences follow different prefixes."""
+    fwd = _last_logits_fn(cfg)
+    got = [int(t) for t in req.generated]
+    seq = [int(t) for t in req.prompt]
+    for j in range(req.max_new_tokens):
+        pad = _REF_WIDTH - len(seq)
+        tokens = np.zeros((1, _REF_WIDTH), np.int32)
+        tokens[0, pad:] = seq
+        logits = np.asarray(fwd(params, jnp.asarray(tokens),
+                                jnp.asarray([pad], jnp.int32)), np.float32)
+        want = int(logits.argmax())
+        if j < len(got):
+            served = got[j]
+        else:
+            assert req.stop_token is not None, (
+                f"{what}: {len(got)} of {req.max_new_tokens} tokens and no "
+                f"stop token")
+            served = req.stop_token
+        if served != want:
+            top2 = np.sort(logits)[-2:]
+            assert top2[1] - top2[0] <= NEAR_TIE, (
+                f"{what}: token {j} is {served}, the full forward picks "
+                f"{want} at a top-2 margin of {top2[1] - top2[0]}")
+            return
+        if want == req.stop_token:
+            assert len(got) == j, f"{what}: served past its stop token"
+            return
+        seq.append(want)
+    assert len(got) == req.max_new_tokens, what
+
+
+# the full and a right-sized page pool; mamba2 has no attention layers, so
+# its pool holds 0 layers and only the per-slot state is live
+POOLS = [("olmo-1b-smoke", {}),
+         ("olmo-1b-smoke", {"page_size": 8, "num_pages": 13}),
+         ("mamba2-780m-smoke", {})]
+
+
+@pytest.mark.parametrize("arch,pool", POOLS)
+def test_mixed_length_batch_matches_solo(arch, pool):
     """Regression for the min-length truncation bug: a batch of unequal
-    prompt lengths must produce, for every request, exactly the tokens it
-    would produce alone (left-padding + masked prefill)."""
-    cfg, params = olmo_setup
+    prompt lengths must produce, for every request, the tokens it would
+    produce alone (left-padding + masked prefill)."""
+    cfg, params = _setup(arch)
     rng = np.random.default_rng(3)
     reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (plen,),
                                         dtype=np.int32), max_new_tokens=6)
             for plen in (3, 11, 7)]
-    eng = ServeEngine(cfg, params, batch_size=3, max_len=64)
+    eng = ServeEngine(cfg, params, batch_size=3, max_len=64, **pool)
     eng.generate(reqs)
     for r in reqs:
-        np.testing.assert_array_equal(
-            r.generated, _solo_tokens(cfg, params, r),
-            err_msg=f"prompt len {r.prompt.shape[-1]} corrupted by batching")
+        _assert_matches_forward(
+            cfg, params, r,
+            f"{arch} prompt len {r.prompt.shape[-1]} (batched)")
 
 
 def test_cache_overflow_rejected(olmo_setup):
@@ -135,8 +188,7 @@ def test_per_request_max_new_tokens(olmo_setup):
     eng.generate(reqs)
     assert [r.generated.shape[-1] for r in reqs] == [2, 7, 4]
     for r in reqs:
-        np.testing.assert_array_equal(
-            r.generated, _solo_tokens(cfg, params, r, max_len=32))
+        _assert_matches_forward(cfg, params, r)
 
 
 def test_stop_token_early_exit(olmo_setup):
@@ -159,21 +211,25 @@ def test_stop_token_early_exit(olmo_setup):
     assert other.generated.shape == (8,)
 
 
-def test_continuous_batching_recycles_slots(olmo_setup):
+@pytest.mark.parametrize("arch,pool", POOLS)
+def test_continuous_batching_recycles_slots(arch, pool):
     """More requests than slots with unequal lengths/budgets: every request
-    completes with exactly its solo tokens (early admission into freed
-    slots must not leak the previous occupant's cache)."""
-    cfg, params = olmo_setup
+    completes with its solo tokens (early admission into freed slots must
+    not leak the previous occupant's cache or state), and every page is
+    back in the pool when the run drains (per-slot compaction for free)."""
+    cfg, params = _setup(arch)
     rng = np.random.default_rng(11)
     reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (plen,),
                                         dtype=np.int32), max_new_tokens=n)
             for plen, n in ((5, 3), (9, 6), (4, 8), (7, 2), (6, 5))]
-    eng = ServeEngine(cfg, params, batch_size=2, max_len=64)
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=64, **pool)
     eng.generate(reqs)
     for i, r in enumerate(reqs):
-        np.testing.assert_array_equal(
-            r.generated, _solo_tokens(cfg, params, r),
-            err_msg=f"request {i} corrupted by slot recycling")
+        _assert_matches_forward(cfg, params, r,
+                                f"{arch} request {i} (slot recycling)")
+    owner = np.asarray(eng._owner)
+    assert owner[0] == -2 and (owner[1:] == -1).all(), \
+        f"pages leaked after drain: {owner}"
 
 
 def test_decode_matches_forward_argmax(olmo_setup):
@@ -197,7 +253,7 @@ def test_ring_cache_mixed_lengths_grouped():
     cfg = get_config("mixtral-8x22b-smoke")   # sliding_window=64
     params = init_params(cfg, jax.random.PRNGKey(0))
     eng = ServeEngine(cfg, params, batch_size=2, max_len=96)  # 96 > window
-    assert eng._ring and not eng._padded_ok
+    assert cfg.sliding_window < 96 and not eng._padded_ok
     rng = np.random.default_rng(5)
     reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (plen,),
                                         dtype=np.int32), max_new_tokens=3)
@@ -213,14 +269,12 @@ def test_ring_cache_mixed_lengths_grouped():
 
 def test_temperature_zero_matches_greedy(olmo_setup):
     cfg, params = olmo_setup
-    prompt = np.arange(6, dtype=np.int32)
-    greedy = Request(prompt=prompt.copy(), max_new_tokens=4)
-    tzero = Request(prompt=prompt.copy(), max_new_tokens=4, temperature=0.0)
+    tzero = Request(prompt=np.arange(6, dtype=np.int32), max_new_tokens=4,
+                    temperature=0.0)
     eng = ServeEngine(cfg, params, batch_size=2, max_len=32, temperature=0.7)
     # engine default 0.7 applies only where the request doesn't override
     eng.generate([tzero])
-    np.testing.assert_array_equal(tzero.generated,
-                                  _solo_tokens(cfg, params, greedy))
+    _assert_matches_forward(cfg, params, tzero)
 
 
 def test_temperature_sampling_decodes_valid_tokens(olmo_setup):
@@ -254,65 +308,37 @@ def test_serve_step_matches_engine_stepping():
 
 
 # ---------------------------------------------------------------------------
-# paged KV cache engine
+# the page pool
 # ---------------------------------------------------------------------------
 
-def test_paged_mixed_length_batch_matches_solo(olmo_setup):
-    """Paged cache, mixed prompt lengths: every request produces exactly the
-    tokens the CONTIGUOUS single-request engine produces — the two cache
-    layouts are token-identical by construction."""
+def test_contiguous_continuous_cache_rejected(olmo_setup):
+    """``paged=False`` names the removed contiguous continuous cache: the
+    constructor refuses it rather than silently serving paged."""
     cfg, params = olmo_setup
-    rng = np.random.default_rng(3)
-    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (plen,),
-                                        dtype=np.int32), max_new_tokens=6)
-            for plen in (3, 11, 7)]
-    eng = ServeEngine(cfg, params, batch_size=3, max_len=64, paged=True,
-                      page_size=8, num_pages=13)
-    eng.generate(reqs)
-    for r in reqs:
-        np.testing.assert_array_equal(
-            r.generated, _solo_tokens(cfg, params, r),
-            err_msg=f"prompt len {r.prompt.shape[-1]} corrupted by paging")
-
-
-def test_paged_recycling_reclaims_pages(olmo_setup):
-    """More requests than slots: tokens match solo AND every page is back
-    in the pool when the run drains (per-slot compaction for free)."""
-    cfg, params = olmo_setup
-    rng = np.random.default_rng(11)
-    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (plen,),
-                                        dtype=np.int32), max_new_tokens=n)
-            for plen, n in ((5, 3), (9, 6), (4, 8), (7, 2), (6, 5))]
-    eng = ServeEngine(cfg, params, batch_size=2, max_len=64, paged=True,
-                      page_size=8, num_pages=13)
-    eng.generate(reqs)
-    for i, r in enumerate(reqs):
-        np.testing.assert_array_equal(
-            r.generated, _solo_tokens(cfg, params, r),
-            err_msg=f"request {i} corrupted by paged slot recycling")
-    owner = np.asarray(eng._owner)
-    assert owner[0] == -2 and (owner[1:] == -1).all(), \
-        f"pages leaked after drain: {owner}"
+    with pytest.raises(ValueError, match="contiguous continuous cache"):
+        ServeEngine(cfg, params, batch_size=2, max_len=32, paged=False)
 
 
 def test_paged_lower_resident_bytes_than_contiguous(olmo_setup):
     """At equal traffic a right-sized page pool keeps fewer resident cache
-    bytes than the contiguous (batch, max_len) cache — the paging payoff."""
+    bytes than the default full pool (``batch * max_len`` positions, as a
+    contiguous cache would) — the paging payoff."""
     cfg, params = olmo_setup
     def mk():
         rng = np.random.default_rng(5)
         return [Request(prompt=rng.integers(0, cfg.vocab_size, (p,),
                                             dtype=np.int32),
                         max_new_tokens=4) for p in (6, 9, 5, 8)]
-    eng_c = ServeEngine(cfg, params, batch_size=4, max_len=128)
-    eng_p = ServeEngine(cfg, params, batch_size=4, max_len=128, paged=True,
+    eng_full = ServeEngine(cfg, params, batch_size=4, max_len=128,
+                           page_size=8)
+    eng_p = ServeEngine(cfg, params, batch_size=4, max_len=128,
                         page_size=8, num_pages=13)
     a, b = mk(), mk()
-    eng_c.generate(a)
+    eng_full.generate(a)
     eng_p.generate(b)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x.generated, y.generated)
-    assert 0 < eng_p.cache_bytes_resident < eng_c.cache_bytes_resident
+    assert 0 < eng_p.cache_bytes_resident < eng_full.cache_bytes_resident
 
 
 def test_paged_stop_token_and_budgets(olmo_setup):
@@ -322,13 +348,12 @@ def test_paged_stop_token_and_budgets(olmo_setup):
     cfg, params = olmo_setup
     reqs = [Request(prompt=np.arange(4, dtype=np.int32), max_new_tokens=n)
             for n in (2, 7, 4)]
-    eng = ServeEngine(cfg, params, batch_size=3, max_len=32, paged=True,
-                      page_size=4, num_pages=13)
+    eng = ServeEngine(cfg, params, batch_size=3, max_len=32, page_size=4,
+                      num_pages=13)
     eng.generate(reqs)
     assert [r.generated.shape[-1] for r in reqs] == [2, 7, 4]
     for r in reqs:
-        np.testing.assert_array_equal(
-            r.generated, _solo_tokens(cfg, params, r, max_len=32))
+        _assert_matches_forward(cfg, params, r)
 
     # a stop token that actually fires: truncates at the un-stopped run's
     # first repeat-free position, and the drained pool holds no pages
@@ -350,8 +375,8 @@ def test_paged_pool_too_small_rejected(olmo_setup):
     """A request whose worst-case page span exceeds the pool must be
     rejected at generate() time, not starve the allocator mid-decode."""
     cfg, params = olmo_setup
-    eng = ServeEngine(cfg, params, batch_size=1, max_len=64, paged=True,
-                      page_size=8, num_pages=4)  # 3 allocatable pages
+    eng = ServeEngine(cfg, params, batch_size=1, max_len=64, page_size=8,
+                      num_pages=4)  # 3 allocatable pages
     ok = Request(prompt=np.arange(8, dtype=np.int32), max_new_tokens=16)
     bad = Request(prompt=np.arange(8, dtype=np.int32), max_new_tokens=17)
     with pytest.raises(ValueError, match="grow the pool"):
@@ -365,8 +390,8 @@ def test_paged_falls_back_for_ring_cache():
     the grouped contiguous fallback and still serves correctly."""
     cfg = get_config("mixtral-8x22b-smoke")   # sliding_window=64
     params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = ServeEngine(cfg, params, batch_size=2, max_len=96, paged=True)
-    assert eng._ring and not eng._paged
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=96)
+    assert cfg.sliding_window < 96 and not eng._paged
     reqs = [Request(prompt=np.arange(4, dtype=np.int32), max_new_tokens=3)
             for _ in range(2)]
     eng.generate(reqs)
@@ -387,7 +412,6 @@ def test_paged_kernel_engine_matches_gather_path(arch, monkeypatch):
 
     from repro.kernels import paged_kv
 
-    NEAR_TIE = 1e-3
     cfg = get_config(arch)
     params = init_params(cfg, jax.random.PRNGKey(2))
     rng = np.random.default_rng(4)
@@ -396,8 +420,7 @@ def test_paged_kernel_engine_matches_gather_path(arch, monkeypatch):
     def serve():
         reqs = [Request(prompt=rng_prompts[i], max_new_tokens=n)
                 for i, (_, n) in enumerate(shapes)]
-        eng = ServeEngine(cfg, params, batch_size=2, max_len=48, paged=True,
-                          page_size=8)
+        eng = ServeEngine(cfg, params, batch_size=2, max_len=48, page_size=8)
         eng.generate(reqs)
         return [r.generated for r in reqs]
 
