@@ -26,6 +26,20 @@ allocator in :mod:`repro.serve.paging`):
   each request's page span against the pool up front — decode can never
   write past the cache depth or run out of pages.
 
+The continuous loop never waits on the device before it dispatches the
+next decode step: it reads each step's tokens one step late, while the
+following step runs. Everything it decides before a dispatch is known on
+the host without a read — a slot finishes its token budget at a step fixed
+by the budget, so the cursor of every admission and the live slots at
+every page boundary are too. The step's token input stays on the device
+(the previous step's output, with each admission's first token written
+into its row), and the allocator's ``ok`` flags are read with the next
+token read. Reads go through a snapshot of which request each row served
+at dispatch, since a slot may hold another request by the time its old
+tokens arrive. Only a stop token is seen one step late: the slot runs one
+extra step inside its own reservation (see ``_take_batch``), whose token
+is dropped, and is freed when the stop is read.
+
 Text models of one mixer per layer with no ring cache have a paged layout
 (:func:`~repro.models.transformer.has_paged_layout`): dense, MoE, SSM and
 the per-layer pattern hybrids (granite-4.0-h), whose Mamba-2 layers keep a
@@ -257,18 +271,45 @@ class Request:
 
 
 @dataclasses.dataclass
-class _Slot:
-    req: Optional[Request] = None
+class _Stream:
+    """One request of the continuous loop, in slot ``slot``. ``issued``
+    counts the tokens that dispatched programs produce for it; ``tokens``
+    holds those read back so far, one step later."""
+    req: Request
+    slot: int
+    issued: int = 1  # the prefill's first token
     tokens: List[int] = dataclasses.field(default_factory=list)
-    done: bool = True
+    closed: bool = False
 
-    def activate(self, req: Request):
-        self.req, self.tokens, self.done = req, [], False
+    @property
+    def spent(self) -> bool:
+        """Its whole budget is dispatched: known before any read."""
+        return self.issued >= self.req.max_new_tokens
 
-    def finish(self):
-        self.done = True
-        if self.req is not None:
-            self.req.generated = np.asarray(self.tokens, np.int32)
+    def take(self, t: int) -> bool:
+        """Record a token read back; True when it is the stop token. Tokens
+        after the stop (the one extra step) are dropped."""
+        if self.closed:
+            return False
+        if self.req.stop_token is not None and t == self.req.stop_token:
+            self.close()
+            return True
+        self.tokens.append(t)
+        if len(self.tokens) >= self.req.max_new_tokens:
+            self.close()
+        return False
+
+    def close(self) -> None:
+        self.closed = True
+        self.req.generated = np.asarray(self.tokens, np.int32)
+
+
+@jax.jit
+def _put_token(tokens, slot, tok):
+    """Write an admission's first token ``tok`` (1, 1) into row ``slot`` of
+    the step's token input (B, 1), on the device; ``slot`` is traced, so one
+    program serves every slot."""
+    return tokens.at[slot].set(tok[0])
 
 
 _ADMIT_ALIGN = 8  # admission prompts pad to multiples of this (fewer traces)
@@ -344,6 +385,13 @@ class ServeEngine:
             raise ValueError(f"num_pages must be >= 2 (page 0 is the trash "
                              f"page), got {self._num_pages}")
         self.cache_bytes_resident = 0
+        # decode steps of the continuous loop, and those dispatched while
+        # the previous step's tokens were still unread (per ``generate``)
+        self.steps = 0
+        self.steps_ahead = 0
+        # allocator ``ok`` flags not yet read: (flag, where it was taken)
+        self._unchecked: List[Tuple[jax.Array, str]] = []
+        self._reserved: Dict[int, int] = {}  # slot -> pages, in the loop
 
     # read by chipbench/test_chipbench_hybrid.py
     _paged = property(lambda self: self._padded_ok)
@@ -391,6 +439,7 @@ class ServeEngine:
     def generate(self, requests: List[Request]) -> List[Request]:
         self._validate(requests)
         self.cache_bytes_resident = 0
+        self.steps = self.steps_ahead = 0
         ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
         with ctx:
@@ -418,7 +467,18 @@ class ServeEngine:
         ``P + max_new <= max_len`` (padding consumes cache depth), and the
         members' worst-case page spans (prompt + full token budget,
         page-rounded — the reservation that keeps allocation infallible)
-        must fit the pool together."""
+        must fit the pool together.
+
+        The reservation also covers the one step a slot runs after its
+        stop token, before the loop reads it. A stop on token ``k`` of a
+        budget ``n`` admitted at cursor ``a`` (token ``k`` is written at
+        position ``a + k``) is read after step ``a + k`` is dispatched; for
+        ``k <= n - 2`` that step writes position ``a + k <= a + n - 2``,
+        inside the reserved span ``[.., a + n)``, and a page it crosses into
+        is one the span already counts. A stop on token ``n - 1`` coincides
+        with the budget finish, which the host knows without a read, so no
+        extra step runs. The slot's pages return when the stop is read, and
+        only then is the slot admitted into again."""
         batch: List[Request] = []
         pad = 0
         i = 0
@@ -450,74 +510,91 @@ class ServeEngine:
         cfg = self.cfg
         B = self.batch_size
         PS = self._page_size
-        slots = [_Slot() for _ in range(B)]
-        for s, r in zip(slots, batch):
-            s.activate(r)
-        plens = [int(s.req.prompt.shape[-1]) if s.req is not None
-                 else int(batch[0].prompt.shape[-1]) for s in slots]
+        live: List[Optional[_Stream]] = [None] * B  # slot -> its request
+        for i, r in enumerate(batch):
+            live[i] = _Stream(r, i)
+        plens = [int((s.req if s else batch[0]).prompt.shape[-1])
+                 for s in live]
         pad = max(plens)
         tokens = np.zeros((B, pad), np.int32)
-        for i, s in enumerate(slots):
-            prm = (s.req or batch[0]).prompt
-            tokens[i, pad - plens[i]:] = prm
+        for i, s in enumerate(live):
+            tokens[i, pad - plens[i]:] = (s.req if s else batch[0]).prompt
         start = np.asarray([pad - p for p in plens], np.int32)
-        temps = np.asarray([self._temp_of(s.req) if s.req else 0.0
-                            for s in slots], np.float32)
-        reserved: Dict[int, int] = {}  # slot -> worst-case page span
+        temps = np.asarray([self._temp_of(s.req) if s else 0.0
+                            for s in live], np.float32)
+        # slot -> worst-case page span, set before its pages are mapped
+        self._reserved = reserved = {}
+        self._unchecked = []
         with TraceAnnotation("serve.pool_init"):
             cache = init_paged_cache(cfg, B, self.max_len, page_size=PS,
                                      num_pages=self._num_pages,
                                      dtype=self._cache_dtype)
             self._owner = page_state_init(self._num_pages, B,
                                           self._max_pages).owner
-            for i, s in enumerate(slots):
-                if s.req is None:
+            for i, s in enumerate(live):
+                if s is None:
                     continue  # empty slot: writes land in the trash page
-                cache = self._palloc(cache, i, int(start[i]) // PS,
-                                     (pad - 1) // PS)
                 reserved[i] = pages_for_span(
                     int(start[i]), pad + s.req.max_new_tokens, PS)
+                cache = self._palloc(cache, i, int(start[i]) // PS,
+                                     (pad - 1) // PS)
             self._note_cache(cache)
+        # device copies of start and temps, made again only on admission;
+        # jnp.array copies, since the host edits both arrays in place
+        start_d, temps_d = jnp.array(start), jnp.array(temps)
         with TraceAnnotation("serve.prefill"):
             nxt, cache = self._prefill(
                 self.params, {"tokens": jnp.asarray(tokens)}, cache,
-                jnp.asarray(start), jnp.asarray(temps), self._next_key())
+                start_d, temps_d, self._next_key())
+        # results not yet read: (token array, the request of each row when
+        # it was dispatched); read after the next step is dispatched
+        unread = [(nxt, tuple(live))]
         cur = pad
 
-        def record(s: _Slot, t: int) -> None:
-            if s.req.stop_token is not None and t == s.req.stop_token:
-                s.finish()
-                return
-            s.tokens.append(t)
-            if len(s.tokens) >= s.req.max_new_tokens:
-                s.finish()
-
-        def reclaim(i: int, s: _Slot, cache):
+        def free(i: int, cache):
             """Per-slot compaction for free: the instant a slot finishes its
             pages go back to the pool (its decode writes re-route to the
             trash page through the cleared table row)."""
-            if not (s.done and i in reserved):
-                return cache
             st = free_slot_pages_jit(
                 PageState(cache.kv.table, self._owner),
                 jnp.asarray(i, jnp.int32))
             self._owner = st.owner
-            reserved.pop(i, None)
+            reserved.pop(i)
+            live[i] = None
             return self._with_table(cache, st.table)
 
+        def read(unread, cache):
+            """Read the allocator's flags and the queued tokens, and record
+            each token to the request its row served at dispatch; a stop
+            token frees the slot if the request still holds it."""
+            checks, self._unchecked = self._unchecked, []
+            for ok, where in checks:
+                with TraceAnnotation("serve.sync"):
+                    ok = bool(ok)
+                if not ok:  # reservations make this unreachable
+                    raise RuntimeError(
+                        f"page pool exhausted at {where} — reservation "
+                        f"accounting broken")
+            for arr, rows in unread:
+                with TraceAnnotation("serve.sync"):
+                    toks = np.asarray(arr)
+                with TraceAnnotation("serve.record"):
+                    for i, s in enumerate(rows):
+                        if (s is not None and s.take(int(toks[i, 0]))
+                                and live[s.slot] is s):
+                            cache = free(s.slot, cache)
+            return cache
+
         while True:
-            with TraceAnnotation("serve.sync"):
-                toks = np.array(nxt)  # copy: admission may overwrite a row
-            admitted = False
             with TraceAnnotation("serve.record"):
-                for i, s in enumerate(slots):
-                    if not s.done and s.req is not None:
-                        record(s, int(toks[i, 0]))
-                        cache = reclaim(i, s, cache)
+                for i, s in enumerate(live):
+                    if s is not None and s.spent:
+                        cache = free(i, cache)
             # early slot recycling: prefill the next request into a finished
             # slot just below the shared cursor (start masks older rows)
-            for i, s in enumerate(slots):
-                if not s.done or not pending:
+            admitted = False
+            for i in range(B):
+                if live[i] is not None or not pending:
                     continue
                 j = self._admittable(pending, cur, reserved)
                 if j is None:
@@ -525,50 +602,53 @@ class ServeEngine:
                 r = pending.pop(j)
                 plen = int(r.prompt.shape[-1])
                 with TraceAnnotation("serve.admit"):
-                    cache = self._palloc(cache, i, (cur - plen) // PS,
-                                         (cur - 1) // PS)
                     reserved[i] = pages_for_span(
                         cur - plen, cur + r.max_new_tokens, PS)
+                    cache = self._palloc(cache, i, (cur - plen) // PS,
+                                         (cur - 1) // PS)
                     tok0, cache = self._admit(r, cache, i, cur)
-                    s.activate(r)
+                    nxt = _put_token(nxt, jnp.asarray(i, jnp.int32), tok0)
+                    s = live[i] = _Stream(r, i)
+                    unread.append((tok0, (s,)))
                     start[i] = cur - plen
                     temps[i] = self._temp_of(r)
-                    toks[i, 0] = tok0
-                    record(s, tok0)  # the admission prefill's first token
-                    cache = reclaim(i, s, cache)
+                    if s.spent:
+                        cache = free(i, cache)
                 admitted = True
-            if all(s.done or s.req is None for s in slots):
+            if all(s is None for s in live):
                 break
             if cur >= self.max_len:  # defensive: budgets guarantee this
-                for s in slots:      # never trips (validated runways)
-                    if not s.done:
-                        s.finish()
-                break
+                break                # never trips (validated runways)
             if cur % PS == 0:
                 # the shared cursor crosses into a fresh logical page: every
                 # live slot gets one (reservation makes this infallible)
-                act = [i for i, s in enumerate(slots) if not s.done]
-                if act:
-                    with TraceAnnotation("serve.page_alloc"):
-                        st, ok = alloc_step_pages_jit(
-                            PageState(cache.kv.table, self._owner),
-                            jnp.asarray(act, jnp.int32),
-                            jnp.asarray(cur // PS, jnp.int32))
-                        with TraceAnnotation("serve.sync"):
-                            ok = bool(ok)
-                        if not ok:  # reservations make this unreachable
-                            raise RuntimeError(
-                                "page pool exhausted at the decode boundary "
-                                "— reservation accounting broken")
-                        self._owner = st.owner
-                        cache = self._with_table(cache, st.table)
+                act = [i for i, s in enumerate(live) if s is not None]
+                with TraceAnnotation("serve.page_alloc"):
+                    st, ok = alloc_step_pages_jit(
+                        PageState(cache.kv.table, self._owner),
+                        jnp.asarray(act, jnp.int32),
+                        jnp.asarray(cur // PS, jnp.int32))
+                    self._unchecked.append((ok, "the decode boundary"))
+                    self._owner = st.owner
+                    cache = self._with_table(cache, st.table)
             with TraceAnnotation("serve.dispatch"):
                 if admitted:
-                    nxt = jnp.asarray(toks)
-                nxt, cache = self._step(self.params, nxt, cache,
-                                        jnp.asarray(start),
-                                        jnp.asarray(temps), self._next_key())
+                    start_d, temps_d = jnp.array(start), jnp.array(temps)
+                self.steps += 1
+                self.steps_ahead += bool(unread)
+                nxt, cache = self._step(self.params, nxt, cache, start_d,
+                                        temps_d, self._next_key())
+            for s in live:
+                if s is not None:
+                    s.issued += 1
+            # the previous step's tokens, while this one runs
+            cache = read(unread, cache)
+            unread = [(nxt, tuple(live))]
             cur += 1
+        read(unread, cache)
+        for s in live:  # only after the defensive break
+            if s is not None and not s.closed:
+                s.close()
 
     def _admittable(self, pending: List[Request], cur: int,
                     reserved: Dict[int, int]) -> Optional[int]:
@@ -596,17 +676,14 @@ class ServeEngine:
 
     def _palloc(self, cache: DecodeCache, slot: int, lo_page: int,
                 hi_page: int) -> DecodeCache:
-        """Map fresh pool pages at ``slot``'s logical pages [lo, hi]."""
+        """Map fresh pool pages at ``slot``'s logical pages [lo, hi]; the
+        allocator's ``ok`` flag is read with the loop's next token read."""
         with TraceAnnotation("serve.page_alloc"):
             logical = jnp.arange(lo_page, hi_page + 1, dtype=jnp.int32)
             st, ok = alloc_slot_pages_jit(
                 PageState(cache.kv.table, self._owner),
                 jnp.asarray(slot, jnp.int32), logical)
-            with TraceAnnotation("serve.sync"):
-                ok = bool(ok)
-            if not ok:  # reservations make this unreachable
-                raise RuntimeError("page pool exhausted at prefill/admission "
-                                   "— reservation accounting broken")
+            self._unchecked.append((ok, "prefill/admission"))
             self._owner = st.owner
             return self._with_table(cache, st.table)
 
@@ -614,8 +691,9 @@ class ServeEngine:
         """Prefill ``r`` alone and splice its KV rows into ``slot``'s
         freshly allocated pages at virtual positions ``[cur - plen, cur)``,
         and its Mamba-2 state into ``slot``'s state row; returns (first
-        token, cache). Under a mesh the prefill runs replicated over the
-        data axes with the running batch's TP specs."""
+        token, on the device as (1, 1), and cache). Under a mesh the
+        prefill runs replicated over the data axes with the running batch's
+        TP specs."""
         plen = int(r.prompt.shape[-1])
         p_adm = min(-(-plen // _ADMIT_ALIGN) * _ADMIT_ALIGN, cur)
         fn = self._admit_fn(p_adm)
@@ -627,9 +705,7 @@ class ServeEngine:
                         jnp.asarray([p_adm - plen], jnp.int32),
                         jnp.asarray([self._temp_of(r)], jnp.float32),
                         self._next_key())
-        with TraceAnnotation("serve.sync"):
-            tok0 = int(np.asarray(nxt)[0, 0])
-        return tok0, cache
+        return nxt, cache
 
     def _admit_fn(self, p_adm: int):
         """Jitted single-request admission prefill, cached per padded

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.serve.engine as engine_mod
 from repro.configs import get_config
 from repro.models.transformer import Model, init_params
 from repro.serve.engine import (
@@ -18,6 +19,7 @@ from repro.serve.engine import (
     select_tokens,
     temperature_sample,
 )
+from repro.serve.paging import pages_for_span
 
 
 def test_greedy_sample():
@@ -458,3 +460,143 @@ def test_paged_kernel_engine_matches_gather_path(arch, monkeypatch):
         assert top2[1] - top2[0] <= NEAR_TIE, (
             f"{arch}: token {d} differs at a top-2 margin of "
             f"{top2[1] - top2[0]}")
+
+
+# ---------------------------------------------------------------------------
+# dispatch ahead: each step's tokens are read while the next step runs
+# ---------------------------------------------------------------------------
+
+def _record_admissions(monkeypatch):
+    """Wrap ``ServeEngine._admit``; returns the list it fills with
+    (request, slot, cursor) of every admission."""
+    admitted = []
+    admit = ServeEngine._admit
+    monkeypatch.setattr(
+        ServeEngine, "_admit", lambda self, r, cache, slot, cur: (
+            admitted.append((r, slot, cur)),
+            admit(self, r, cache, slot, cur))[1])
+    return admitted
+
+
+def _budget_schedule(sizes, batch: int, pad: int):
+    """(admission cursors, cursor of the last finish) when every request
+    runs to its budget: a request admitted at cursor ``a`` with a budget of
+    ``n`` leaves its slot at ``a + n - 1``, and the next pending request
+    takes the slot at that cursor (the lowest slot first on a tie)."""
+    free_at = [pad + n - 1 for _, n in sizes[:batch]]
+    cursors = []
+    for _, n in sizes[batch:]:
+        i = free_at.index(min(free_at))
+        cursors.append(free_at[i])
+        free_at[i] += n - 1
+    return cursors, max(free_at)
+
+
+@pytest.mark.parametrize("arch,pool", POOLS)
+def test_dispatch_ahead_keeps_the_budget_schedule(arch, pool, monkeypatch):
+    """Budget-only traffic, more requests than slots, over several page
+    boundaries: every decode step (the first too, which takes the batch
+    prefill's tokens on the device) is dispatched before the previous
+    step's tokens are read; admissions land at the cursors the budgets
+    alone give; every request gets the tokens of the cache-free forward."""
+    cfg, params = _setup(arch)
+    rng = np.random.default_rng(13)
+    sizes = ((5, 9), (9, 14), (4, 11), (7, 6), (6, 12), (3, 10), (8, 13))
+    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (p,),
+                                        dtype=np.int32), max_new_tokens=n)
+            for p, n in sizes]
+    admitted = _record_admissions(monkeypatch)
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=64,
+                      **{"page_size": 8, **pool})
+    eng.generate(reqs)
+    pad = max(p for p, _ in sizes[:2])
+    cursors, last = _budget_schedule(sizes, 2, pad)
+    assert [r for r, _, _ in admitted] == reqs[2:]
+    assert [c for _, _, c in admitted] == cursors
+    assert last - pad > 3 * 8, "the run crosses several page boundaries"
+    assert eng.steps == last - pad
+    assert eng.steps_ahead == eng.steps
+    for i, r in enumerate(reqs):
+        _assert_matches_forward(cfg, params, r, f"{arch} request {i}")
+    owner = np.asarray(eng._owner)
+    assert owner[0] == -2 and (owner[1:] == -1).all(), owner
+
+
+def _pages_within_reservations(eng, monkeypatch):
+    """After every page allocation, each slot owns at most the pages it
+    reserved, and no page belongs to a slot without a reservation."""
+    def checked(fn):
+        def call(state, *a):
+            st, ok = fn(state, *a)
+            owner = np.asarray(st.owner)
+            assert set(owner[owner >= 0].tolist()) <= set(eng._reserved)
+            for slot, n in eng._reserved.items():
+                assert (owner == slot).sum() <= n, (slot, n, owner)
+            return st, ok
+        return call
+
+    for name in ("alloc_slot_pages_jit", "alloc_step_pages_jit"):
+        monkeypatch.setattr(engine_mod, name,
+                            checked(getattr(engine_mod, name)))
+
+
+@pytest.mark.parametrize("case", ["mid_page", "page_boundary",
+                                  "last_token"])
+def test_late_stop_token(olmo_setup, monkeypatch, case):
+    """A stop token is read one step late. Before its last budget token
+    the slot runs one extra step (``page_boundary``: that step crosses
+    into a fresh page), inside its reservation, and a pending request takes
+    the slot at the next cursor; on its last budget token the host already
+    knows the finish and admits at once. Every request gets the tokens of
+    the cache-free forward, and the pool drains."""
+    cfg, params = olmo_setup
+    ps, pad, n = 4, 9, 16
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, cfg.vocab_size, (6,), dtype=np.int32)
+    base = Request(prompt=prompt, max_new_tokens=n)
+    ServeEngine(cfg, params, batch_size=1, max_len=48,
+                page_size=ps).generate([base])
+    g = base.generated.tolist()
+    # a stop on token k fires at k only where the token is new there;
+    # token k is written at position pad + k by the extra step
+    new = [k for k in range(1, n - 1) if g[k] not in g[:k]]
+    if case == "last_token":
+        k = new[0]
+        n = k + 1
+    else:
+        k = next(k for k in new if ((pad + k) % ps == 0)
+                 == (case == "page_boundary"))
+    stopped = Request(prompt=prompt, max_new_tokens=n, stop_token=g[k])
+    other = Request(prompt=rng.integers(0, cfg.vocab_size, (pad,),
+                                        dtype=np.int32), max_new_tokens=20)
+    waiting = Request(prompt=rng.integers(0, cfg.vocab_size, (5,),
+                                          dtype=np.int32), max_new_tokens=6)
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=48, page_size=ps)
+    admitted = _record_admissions(monkeypatch)
+    _pages_within_reservations(eng, monkeypatch)
+    eng.generate([stopped, other, waiting])
+    np.testing.assert_array_equal(stopped.generated, g[:k])
+    late = 0 if case == "last_token" else 1
+    assert [(r, slot, cur) for r, slot, cur in admitted] == [
+        (waiting, 0, pad + k + late)]
+    for r in (stopped, other, waiting):
+        _assert_matches_forward(cfg, params, r, case)
+    owner = np.asarray(eng._owner)
+    assert owner[0] == -2 and (owner[1:] == -1).all(), \
+        f"late stop leaked pages: {owner}"
+
+
+def test_broken_reservation_raises_before_generate_returns(olmo_setup,
+                                                           monkeypatch):
+    """The allocator's ``ok`` flags are read after the next dispatch, not
+    at once; a reservation that under-counts by a page still makes
+    ``generate`` raise before it returns."""
+    cfg, params = olmo_setup
+    monkeypatch.setattr(engine_mod, "pages_for_span",
+                        lambda s, e, ps: max(0, pages_for_span(s, e, ps) - 1))
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=32, page_size=4,
+                      num_pages=7)
+    reqs = [Request(prompt=np.arange(4, dtype=np.int32), max_new_tokens=12)
+            for _ in range(2)]
+    with pytest.raises(RuntimeError, match="reservation accounting broken"):
+        eng.generate(reqs)
